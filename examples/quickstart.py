@@ -22,10 +22,9 @@ Compression knobs (CompressedGT / QuantizedGT):
                              re-inject it next round (tightens the floor)
   use_kernel                 dispatch lane-aligned leaves to the fused
                              Pallas compress-correction kernel
-                             (kernels/compress_correction.py); pairs
-                             with kernel_interpret — True (default) runs
-                             the CPU interpreter for validation, set
-                             False on real TPU for the compiled kernel
+                             (kernels/compress_correction.py): compiled
+                             on a TPU, interpreted on the CPU; a TPU
+                             refuses the exact-k selection (ratio < 1)
 
 Two finales: FedGDA-GT once more on the ASYNC runtime
 (`fed.async_runtime.AsyncFederatedRunner`): the same four round phases

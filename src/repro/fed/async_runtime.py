@@ -17,10 +17,18 @@ math — changes:
     forming c_i = gbar - g_i, the strategy's `transform_correction`
     (identical code, identical RNG draws as the sync path, so iterates
     match `FederatedRunner` to fp tolerance) and the packed-payload
-    decode — runs on the server device over the gathered gradients;
+    decode — runs on the server device over the gathered gradients.
+    A strategy whose transform is the identity
+    (`transforms_correction` false: plain and partial-participation GT)
+    needs none of that server work per agent: the shards' weighted
+    partial sums of their gradients are added up shard to shard into
+    gbar, which is broadcast, and each shard forms its own c_i — so
+    the server device holds gbar instead of two model copies per
+    agent;
   * `local_steps` runs per shard with its correction slice; the shard
-    returns a weighted PARTIAL aggregate (`core.agent_weighted_sum`),
-    and the server combines + projects;
+    returns a weighted PARTIAL aggregate (`core.agent_weighted_sum`);
+    the partials are added up shard to shard (`_chain_sum`) and the
+    server projects the total;
   * the next round's `broadcast` transfer is **double-buffered**: the
     server enqueues `jax.device_put` of (x^{t+1}, y^{t+1}) to every
     shard device as soon as the aggregate is dispatched (before its
@@ -203,6 +211,11 @@ class AsyncFederatedRunner(RunnerHistoryMixin):
         self._gfn = grad_xy(loss)
         self._vgrad = jax.vmap(self._gfn, in_axes=(0, 0, 0))
         self._use_corr = bool(getattr(self._strategy, "use_correction", False))
+        #: corrections formed shard-side from the broadcast gbar (see
+        #: the module docstring); the server path otherwise
+        self._shard_corr = self._use_corr and not getattr(
+            self._strategy, "transforms_correction", True
+        )
         self._sync_every = bool(
             getattr(self._strategy, "sync_every_step", False)
         )
@@ -266,6 +279,17 @@ class AsyncFederatedRunner(RunnerHistoryMixin):
                 )
             return g.gx, g.gy
 
+        def shard_grad_sums(x, y, data_s, w_s, nk_s=None):
+            """Anchor gradients plus their weighted partial sum — the
+            shard's share of gbar (the shard keeps the gradients)."""
+            gx, gy = shard_grads(x, y, data_s, nk_s)
+            return (gx, gy, agent_weighted_sum(gx, w_s),
+                    agent_weighted_sum(gy, w_s))
+
+        def shard_corrections(gx, gy, gbar_x, gbar_y):
+            """The shard's own c_i = gbar - g_i (identity transform)."""
+            return tracking_corrections(gx, gy, gbar_x, gbar_y, cdt)
+
         def shard_point_grads(x, y, data_s):
             """Per-agent gradients at the SHARED point (FullSync: every
             'local' step is evaluated at the current global iterate)."""
@@ -323,13 +347,11 @@ class AsyncFederatedRunner(RunnerHistoryMixin):
                 agent_weighted_sum(rs.ys, w_s),
             )
 
-        def server_combine(x_sums, y_sums):
-            """Combine the shards' partial aggregates and project.  The
-            shard sums already carry the participation weights (or 1/m
-            for uniform averaging), so the combine is a plain sum."""
-            x1 = jax.tree.map(lambda *u: sum(u), *x_sums)
-            y1 = jax.tree.map(lambda *u: sum(u), *y_sums)
-            return self._proj_x(x1), self._proj_y(y1)
+        def add(total, part):
+            return jax.tree.map(jnp.add, total, part)
+
+        def project(x, y):
+            return self._proj_x(x), self._proj_y(y)
 
         def zeros_like_agents(bx, by):
             """m == 1: the correction is identically zero and elided."""
@@ -355,11 +377,18 @@ class AsyncFederatedRunner(RunnerHistoryMixin):
             )
 
         self._shard_grads = jax.jit(shard_grads)
+        self._shard_grad_sums = jax.jit(shard_grad_sums)
+        # the gradients are dead once the corrections exist: donate them
+        self._shard_corrections = jax.jit(
+            shard_corrections, donate_argnums=(0, 1)
+        )
+        # the running total arrives as a fresh transfer: donate it
+        self._add = jax.jit(add, donate_argnums=(0,))
+        self._project = jax.jit(project)
         self._shard_point_grads = jax.jit(shard_point_grads)
         self._fullsync_step = jax.jit(fullsync_step)
         self._server_exchange = jax.jit(server_exchange)
         self._shard_steps = jax.jit(shard_steps, donate_argnums=(0, 1))
-        self._server_combine = jax.jit(server_combine)
         self._zeros_like_agents = jax.jit(zeros_like_agents)
         self._server_exchange_elastic = jax.jit(server_exchange_elastic)
         self._shard_steps_elastic = jax.jit(shard_steps)
@@ -506,6 +535,19 @@ class AsyncFederatedRunner(RunnerHistoryMixin):
                 out.append((jax.device_put(x, d), jax.device_put(y, d)))
         return out
 
+    def _chain_sum(self, parts: List[Pytree], devices=None) -> Pytree:
+        """Sum per-shard partial sums (they already carry the
+        participation weights, or 1/m) and land the total on the server.
+        The running total hops from shard to shard in order, so each
+        device receives one addend and the server only the total — not
+        one copy per shard — and the order ((p0 + p1) + p2) + ... is the
+        one the single-program sum uses."""
+        devices = self._shard_devices if devices is None else devices
+        total = parts[0]
+        for part, d in zip(parts[1:], devices[1:]):
+            total = self._add(jax.device_put(total, d), part)
+        return jax.device_put(total, self._server)
+
     def _concat_server(self, parts: List[Pytree]) -> Pytree:
         return concat_on_device(parts, self._server)
 
@@ -600,7 +642,31 @@ class AsyncFederatedRunner(RunnerHistoryMixin):
         per = self._per
         cx_s = cy_s = [None] * self._n_shards
         gbx_s = gby_s = [None] * self._n_shards
-        if self._use_corr and self._m > 1:
+        if self._shard_corr and self._m > 1:
+            with maybe_span(tm, "exchange_corrections",
+                            dispatches=self._n_shards):
+                gs = [
+                    self._shard_grad_sums(bx, by, data, wi, nk)
+                    for (bx, by), data, wi, nk in zip(
+                        bcast, self._data_s, w_slices, nk_slices
+                    )
+                ]
+                gbar_x = self._chain_sum([g[2] for g in gs])
+                gbar_y = self._chain_sum([g[3] for g in gs])
+                gbx_s = [
+                    jax.device_put(gbar_x, d) for d in self._shard_devices
+                ]
+                gby_s = [
+                    jax.device_put(gbar_y, d) for d in self._shard_devices
+                ]
+                cs = [
+                    self._shard_corrections(g[0], g[1], gbx, gby)
+                    for g, gbx, gby in zip(gs, gbx_s, gby_s)
+                ]
+                del gs
+                cx_s = [c[0] for c in cs]
+                cy_s = [c[1] for c in cs]
+        elif self._use_corr and self._m > 1:
             # fan-out: every shard's anchor-gradient program is dispatched
             # before any result is awaited (async dispatch == one stream
             # per device); the device_put gathers below overlap shards
@@ -656,9 +722,9 @@ class AsyncFederatedRunner(RunnerHistoryMixin):
                 )
             ]
         with maybe_span(tm, "aggregate"):
-            x1, y1 = self._server_combine(
-                [jax.device_put(a, self._server) for a, _ in sums],
-                [jax.device_put(b, self._server) for _, b in sums],
+            x1, y1 = self._project(
+                self._chain_sum([a for a, _ in sums]),
+                self._chain_sum([b for _, b in sums]),
             )
         # double-buffer flip: enqueue next round's broadcast immediately
         # (the transfers ride behind the still-executing local steps; the
@@ -819,10 +885,11 @@ class AsyncFederatedRunner(RunnerHistoryMixin):
                 for i in range(n)
                 if shard_live[i]
             ]
+        live = [d for d, on in zip(self._shard_devices, shard_live) if on]
         with maybe_span(tm, "aggregate"):
-            x1, y1 = self._server_combine(
-                [jax.device_put(a, self._server) for a, _ in sums],
-                [jax.device_put(b, self._server) for _, b in sums],
+            x1, y1 = self._project(
+                self._chain_sum([a for a, _ in sums], live),
+                self._chain_sum([b for _, b in sums], live),
             )
         return x1, y1, tracker
 

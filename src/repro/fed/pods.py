@@ -56,9 +56,7 @@ def pod_aligned_shard_count(num_pods: int, max_shards: int) -> int:
     return 1
 
 
-def encode_pod_partials(
-    partials: Pytree, *, use_kernel: bool = False, interpret: bool = True
-) -> PackedTree:
+def encode_pod_partials(partials: Pytree) -> PackedTree:
     """Pack per-pod partial aggregates (leaves with a leading pod axis —
     typically only the LIVE pods' rows, gathered by the caller) into a
     `PackedTree` of DENSE payloads.  Dense specs (ratio 1.0, 32 bits)
@@ -73,17 +71,11 @@ def encode_pod_partials(
         base = LeafSpec.build(u.shape[1:], u.dtype, 1.0, 32)
         spec = base.stacked(num_rows)
         flat = u.reshape(num_rows * base.rows, base.cols)
-        payload, _ = encode_leaf(
-            flat, None, None, None, spec,
-            use_kernel=use_kernel, interpret=interpret,
-        )
+        payload, _ = encode_leaf(flat, None, None, None, spec)
         payloads.append(payload)
         specs.append(spec)
         shapes.append(u.shape)
-    return PackedTree(
-        payloads, specs, treedef, shapes,
-        use_kernel=use_kernel, interpret=interpret,
-    )
+    return PackedTree(payloads, specs, treedef, shapes)
 
 
 def pod_payload_bytes(x: Pytree, y: Pytree, *, measured: bool = True) -> int:

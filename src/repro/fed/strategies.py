@@ -33,6 +33,9 @@ Protocol consumed by the engine (all trace-time unless noted):
                      cx/cy may come back as `transport.PackedTree` wire
                      payloads (objects with a `.decode()` hook) instead
                      of dense trees; the engine decodes before use
+  transforms_correction  False when that transform is the identity, so
+                     a sharded runtime may form each c_i on the agent's
+                     own device (`fed.async_runtime`)
   rebase_state(state, active, prev_active) -> state  [traced]
                      re-anchor membership-dependent state for an elastic
                      round's active set (`repro.sim`): compressors zero
@@ -181,6 +184,10 @@ class CommStrategy:
         bitwise-pinned legacy path); otherwise a length-m weight vector
         with sum(w) == 1 used for both gbar and the final aggregate."""
         return None, state
+
+    #: whether `transform_correction` changes the corrections (or the
+    #: state); False is the identity below
+    transforms_correction = False
 
     def transform_correction(
         self, cx: Pytree, cy: Pytree, state: State
@@ -363,7 +370,6 @@ class _CorrectionCompressor(CommStrategy):
     draws, so wire on/off produces bitwise-identical GT iterates."""
 
     use_kernel: bool = False       # fused Pallas path on aligned 2D leaves
-    kernel_interpret: bool = True  # interpret=True is the CPU validation path
     wire_transport: bool = False   # emit packed payloads, not dense trees
     use_correction = True
     # knob defaults, overridden by concrete subclasses' dataclass fields
@@ -409,6 +415,10 @@ class _CorrectionCompressor(CommStrategy):
         # any lossy transform (or gradient noise) voids the
         # anchor-point cancellation
         return not self._active and self.noise is None
+
+    @property
+    def transforms_correction(self) -> bool:
+        return self._active
 
     @property
     def _compressor_state(self) -> bool:
@@ -485,7 +495,6 @@ class _CorrectionCompressor(CommStrategy):
                     payload, resid = encode_leaf(
                         flat, e_flat, u_sel, u_rnd, spec.stacked(m),
                         use_kernel=self.use_kernel,
-                        interpret=self.kernel_interpret,
                     )
                     payloads.append(payload)
                     specs.append(spec.stacked(m))
@@ -500,7 +509,6 @@ class _CorrectionCompressor(CommStrategy):
                         bits=self._bits,
                         mode=self.mode,
                         use_kernel=self.use_kernel,
-                        interpret=self.kernel_interpret,
                     )
                     chat_leaves.append(chat.reshape(c.shape))
                 resid_leaves.append(None if e is None else resid.reshape(c.shape))
@@ -513,7 +521,6 @@ class _CorrectionCompressor(CommStrategy):
                 chat = PackedTree(
                     payloads, specs, treedef, shapes,
                     use_kernel=self.use_kernel,
-                    interpret=self.kernel_interpret,
                 )
             else:
                 chat = jax.tree.unflatten(treedef, chat_leaves)

@@ -213,7 +213,6 @@ def encode_leaf(
     spec: LeafSpec,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> Tuple[LeafPayload, jax.Array]:
     """Pack one leaf into its wire payload.  Returns (payload, resid)
     with resid = (c + e) - decode(payload) in c.dtype — the
@@ -224,8 +223,7 @@ def encode_leaf(
     if use_kernel and _fusable(spec):
         data, idx, scale, resid = pack_payload_2d(
             c, e, u_sel, u_rnd,
-            index_dtype=spec.index_dtype, scale_dtype=spec.scale_dtype,
-            interpret=interpret, **kw,
+            index_dtype=spec.index_dtype, scale_dtype=spec.scale_dtype, **kw
         )
     else:
         data, idx, scale, resid = ref.pack_payload_ref(
@@ -245,7 +243,6 @@ def decode_leaf(
     spec: LeafSpec,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> jax.Array:
     """Scatter-add the packed payload back to the dense [rows, cols]
     compressed correction (bitwise the chat that produced it)."""
@@ -261,9 +258,7 @@ def decode_leaf(
         encoding=spec.encoding,
     )
     if use_kernel and _fusable(spec):
-        return unpack_payload_2d(
-            payload.data, idx, scale, interpret=interpret, **kw
-        )
+        return unpack_payload_2d(payload.data, idx, scale, **kw)
     return ref.decode_payload_ref(payload.data, idx, scale, **kw)
 
 
@@ -276,19 +271,16 @@ class PackedTree:
 
     def __init__(self, payloads: List[LeafPayload], specs: List[LeafSpec],
                  treedef, shapes: List[Tuple[int, ...]],
-                 use_kernel: bool = False, interpret: bool = True):
+                 use_kernel: bool = False):
         self.payloads = payloads
         self.specs = specs
         self.treedef = treedef
         self.shapes = shapes  # original [m, *leaf_shape] shapes
         self.use_kernel = use_kernel
-        self.interpret = interpret
 
     def decode(self) -> Pytree:
         leaves = [
-            decode_leaf(
-                p, s, use_kernel=self.use_kernel, interpret=self.interpret
-            ).reshape(shape)
+            decode_leaf(p, s, use_kernel=self.use_kernel).reshape(shape)
             for p, s, shape in zip(self.payloads, self.specs, self.shapes)
         ]
         return jax.tree.unflatten(self.treedef, leaves)

@@ -15,8 +15,10 @@ The grid tiles rows only — per-row top-k and the per-row quantization
 scale need the full C-length row resident in VMEM, so C must be
 lane-aligned (C % 128 == 0) and one (block_rows, C) tile must fit VMEM;
 correction leaves are (num_agents, prod(param_shape)) so R is small.
-`jax.lax.top_k` / `jnp.cumsum` run on the VPU inside the kernel (and
-trivially under interpret=True, the CPU validation path).
+Pallas has no TPU lowering for the `jax.lax.top_k` of the exact-k
+selection, so on a TPU only the quantize-only configuration (k == C)
+compiles; a selecting one is refused by name (`backend.
+refuse_tpu_lowering`).  Off a TPU the kernel runs in the interpreter.
 
 Selection and rounding randomness comes in as iid U[0,1) inputs rather
 than an in-kernel PRNG: keeping the k largest uniforms IS a uniform
@@ -35,8 +37,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import ref
+from .backend import refuse_tpu_lowering, resolve_interpret
 
 LANE = 128  # TPU lane width: last dim of every tile must be a multiple
+_NO_TPU_SELECT = "Pallas TPU has no lowering for top_k (the exact-k selection)"
 
 
 def _compress_kernel(c_ref, e_ref, us_ref, ur_ref, chat_ref, res_ref, *,
@@ -92,7 +96,7 @@ def compress_correction_2d(
     bits: int = 32,
     mode: str = "topk",
     block_rows: int = 8,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """One fused VMEM pass of (feedback-inject, exact-k select, quantize,
     residual-update) per row.  Returns (chat, resid), both c.dtype and
@@ -100,6 +104,9 @@ def compress_correction_2d(
     R, C = c.shape
     assert C % LANE == 0, f"fused path needs lane-aligned leaves, got C={C}"
     assert mode in ("topk", "randk"), mode
+    interpret = resolve_interpret(interpret)
+    if not interpret and k < C:
+        refuse_tpu_lowering("compress_correction_2d", _NO_TPU_SELECT)
     if bits < 32:
         assert u_rnd is not None, "stochastic rounding (bits<32) needs u_rnd"
     if mode == "randk" and k < C:
@@ -141,7 +148,6 @@ def compress_leaf(
     bits: int = 32,
     mode: str = "topk",
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """Strategy-facing dispatcher: the fused Pallas path on aligned 2D
     leaves, the pure-jnp oracle otherwise.  Both paths are the same math
@@ -150,6 +156,6 @@ def compress_leaf(
     differently than the per-op path)."""
     if use_kernel and fusable_leaf(c):
         return compress_correction_2d(
-            c, e, u_sel, u_rnd, k=k, bits=bits, mode=mode, interpret=interpret
+            c, e, u_sel, u_rnd, k=k, bits=bits, mode=mode
         )
     return ref.compress_correction_ref(c, e, u_sel, u_rnd, k=k, bits=bits, mode=mode)

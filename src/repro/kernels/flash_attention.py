@@ -10,11 +10,14 @@ seq axes; head_dim padded by the caller if needed).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +85,7 @@ def flash_attention(
     softcap: float = 0.0,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, H, Sq, hd = q.shape
     Skv = k.shape[2]
@@ -118,6 +121,6 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(B, H, Sq, hd)

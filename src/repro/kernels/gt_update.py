@@ -9,10 +9,13 @@ conversion.  Tiles are (block_rows, 128) — lane-aligned for the VPU.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .backend import resolve_interpret
 
 
 def _gt_update_kernel(z_ref, g_ref, c_ref, o_ref, *, eta: float, sign: float):
@@ -32,7 +35,7 @@ def gt_update_2d(
     sign: float,
     block_rows: int = 256,
     block_cols: int = 512,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     R, C = z.shape
     br = min(block_rows, R)
@@ -46,5 +49,5 @@ def gt_update_2d(
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(z.shape, z.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(z, g, c)

@@ -1,9 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Every wrapper takes `use_kernel` / `interpret` switches: on CPU (this
-container) the kernels run under interpret=True for validation; on TPU the
-same pallas_calls compile to Mosaic.  `use_kernel=False` falls back to the
-ref oracle (the default inside the model code, which targets both runtimes).
+The kernels compile to Mosaic on a TPU and run in the Pallas interpreter
+elsewhere (`backend.resolve_interpret`).  `make_gt_update_fn(use_kernel=
+False)` falls back to the ref oracle.
 """
 from __future__ import annotations
 
@@ -30,7 +29,7 @@ def _to_2d(u: jax.Array):
     return flat.reshape(rows, cols), pad
 
 
-def make_gt_update_fn(interpret: bool = True, use_kernel: bool = True):
+def make_gt_update_fn(use_kernel: bool = True):
     """Drop-in `update_fn` for core.fedgda_gt.make_fedgda_gt_round."""
 
     def update(z: Pytree, g: Pytree, c: Pytree, eta, sign: float) -> Pytree:
@@ -45,7 +44,7 @@ def make_gt_update_fn(interpret: bool = True, use_kernel: bool = True):
             c2, _ = _to_2d(cv.astype(gv.dtype))
             r = gt_update_2d(
                 u2, g2, c2, eta=float(eta), sign=sign,
-                block_rows=min(256, u2.shape[0]), interpret=interpret,
+                block_rows=min(256, u2.shape[0]),
             )
             return r.reshape(-1)[: u.size].reshape(u.shape)
 
@@ -62,7 +61,6 @@ def grouped_flash_attention(
     causal: bool = True,
     window: int = 0,
     softcap: float = 0.0,
-    interpret: bool = True,
 ) -> jax.Array:
     """GQA adapter: repeats KV groups, runs the kernel, restores layout."""
     B, Sq, H, hd = q.shape
@@ -72,8 +70,7 @@ def grouped_flash_attention(
     kt = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1)
     vt = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1)
     out = flash_attention(
-        qt, kt, vt, causal=causal, window=window, softcap=softcap,
-        interpret=interpret,
+        qt, kt, vt, causal=causal, window=window, softcap=softcap
     )
     return out.transpose(0, 2, 1, 3)
 
@@ -84,7 +81,6 @@ def batched_ssm_scan(
     c_coef: jax.Array,  # [B, S, N]
     *,
     chunk: int = 64,
-    interpret: bool = True,
 ) -> jax.Array:
-    fn = functools.partial(ssm_scan, chunk=chunk, interpret=interpret)
+    fn = functools.partial(ssm_scan, chunk=chunk)
     return jax.vmap(fn)(da, dbx, c_coef)

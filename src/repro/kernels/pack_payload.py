@@ -18,7 +18,13 @@ kernels produce (and consume) the actual packed wire format of
 The grid tiles rows only, like compress_correction: per-row top-k, the
 per-row quantization scale and the per-row index extraction all need the
 full C-length row resident in VMEM, so the fused path requires
-lane-aligned leaves (C % 128 == 0).  The kernel bodies ARE the oracles —
+lane-aligned leaves (C % 128 == 0).  On a TPU only the dense encoding
+lowers: Pallas has no TPU lowering for the exact-k selection (`top_k`),
+the uint32 bit-packing (a reduction over unsigned integers), the
+lane gather of kept values or the scatter-add of the decode, and a
+configuration that needs one is refused by name
+(`backend.refuse_tpu_lowering`; the all-levels unpack lowers but
+overflows VMEM at real leaf widths).  The kernel bodies ARE the oracles —
 each invokes `ref.pack_payload_ref` / `ref.decode_payload_ref` on its
 VMEM-resident block, so kernel == oracle by construction on the same
 uniform draws: data and idx agree BITWISE, values to <= 1 ulp (the
@@ -38,7 +44,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import ref
-from .compress_correction import LANE, _operand, _row_block
+from .backend import refuse_tpu_lowering, resolve_interpret
+from .compress_correction import LANE, _NO_TPU_SELECT, _operand, _row_block
+
+# why each non-dense encoding is refused on a TPU (module docstring)
+_NO_TPU_PACK = {
+    "quant": "Pallas TPU has no lowering for the uint32 bit-packing sum",
+    "quant_dense": "Pallas TPU has no lowering for the uint32 bit-packing sum",
+    "sparse": "Pallas TPU has no lowering for the lane gather of kept values",
+}
+_NO_TPU_UNPACK = {
+    "quant": "Pallas TPU has no lowering for scatter-add",
+    "sparse": "Pallas TPU has no lowering for scatter-add",
+    "quant_dense": "its word unpack overflows VMEM at real leaf widths",
+}
 
 
 def _pack_kernel(c_ref, e_ref, us_ref, ur_ref,
@@ -76,7 +95,7 @@ def pack_payload_2d(
     index_dtype=jnp.int32,
     scale_dtype=None,
     block_rows: int = 8,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One fused VMEM pass of (feedback-inject, exact-k select, quantize,
     index-extract, bit-pack, residual-update) per row block.  Returns
@@ -94,6 +113,11 @@ def pack_payload_2d(
         )
     if mode == "randk" and k < C:
         assert u_sel is not None, "rand-k selection needs u_sel scores"
+    interpret = resolve_interpret(interpret)
+    if not interpret and k < C:
+        refuse_tpu_lowering("pack_payload_2d", _NO_TPU_SELECT)
+    if not interpret and encoding != "dense":
+        refuse_tpu_lowering("pack_payload_2d", _NO_TPU_PACK[encoding])
     if encoding in ("quant", "quant_dense"):
         n = C if encoding == "quant_dense" else k
         data_shape, data_dtype = (R, ref.word_layout(n, bits)[2]), jnp.uint32
@@ -149,12 +173,15 @@ def unpack_payload_2d(
     bits: int = 32,
     encoding: str = "quant",
     block_rows: int = 8,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused unpack + dequantize + scatter-add back to the dense [R, cols]
     compressed correction; bitwise-equal to `ref.decode_payload_ref`."""
     assert cols % LANE == 0, f"fused path needs lane-aligned leaves, got {cols}"
     assert encoding in ("quant", "quant_dense", "sparse", "dense"), encoding
+    interpret = resolve_interpret(interpret)
+    if not interpret and encoding != "dense":
+        refuse_tpu_lowering("unpack_payload_2d", _NO_TPU_UNPACK[encoding])
     R = data.shape[0]
     br = _row_block(R, block_rows)
     row_spec = lambda w: pl.BlockSpec((br, w), lambda i: (i, 0))

@@ -11,11 +11,14 @@ O(chunk * d_block * N) regardless of sequence length.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .backend import resolve_interpret
 
 
 def _ssm_kernel(da_ref, dbx_ref, c_ref, y_ref, h_scr, *, chunk: int):
@@ -44,7 +47,7 @@ def ssm_scan(
     *,
     chunk: int = 64,
     block_d: int = 128,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Returns y [S, D].  Single sequence; vmap over batch."""
     S, D, N = da.shape
@@ -68,5 +71,5 @@ def ssm_scan(
         out_specs=pl.BlockSpec((chunk, block_d), lambda d, c: (c, d)),
         out_shape=jax.ShapeDtypeStruct((S, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(da, dbx, c_coef.reshape(S, 1, N))

@@ -3,13 +3,21 @@
 Runs FedGDA-GT (or a baseline / scenario strategy — any
 `resolve_strategy` name: local_sgda, sync_gda, partial_gt, compressed_gt,
 quantized_gt, and the stochastic family sagda / local_sgda_plus with
-`--noise` / `--momentum`) over one of the assigned architectures on whatever devices
-exist (a host mesh locally; the production mesh on a real cluster), with
+`--noise` / `--momentum`) over one of the registry architectures, with
 synthetic heterogeneous federated data, metrics and checkpointing.  The
-round comes from the phase-split engine (`make_round`), bitwise-identical
-to the legacy constructors for the legacy names (tests/test_engine_parity);
-stateful strategies (sampling RNG, error-feedback buffers) thread their
-state across rounds and into checkpoints.
+sync runtime (the default) jits the whole round as one program on the
+default device, with the parameters, `delta` and the strategy state
+donated to it.  The round comes from the phase-split engine
+(`make_round`), bitwise-identical to the legacy constructors for the
+legacy names (tests/test_engine_parity); stateful strategies (sampling
+RNG, error-feedback buffers) thread their state across rounds and into
+checkpoints.
+
+Two cuts size a registry model for one chip without changing any width:
+`--num-layers N` keeps N layers (a whole number of periods of the layer
+pattern) and `--vocab-size V` keeps the chip's share of the vocabulary
+(the synthetic traffic draws its ids below V).  `--reduced` is the CPU
+smoke preset, which does cut the widths.
 
 `--runtime async` hands the same loss/strategy to
 `fed.async_runtime.AsyncFederatedRunner`: per-agent-shard phase programs
@@ -28,21 +36,32 @@ plus per-round spans, wire-byte counters, opt-in invariant probes
 round spans + wire-byte counters only.  Without the flag nothing is
 constructed and the runners execute their exact pre-telemetry traces.
 
-    PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b --reduced \
-        --rounds 50 --local-steps 8 --agents 4 \
+`main(argv)` is also the programmatic entry point: it returns a
+`TrainResult` with the final iterates and the logged losses.  The
+persistent compilation cache lives where JAX_COMPILATION_CACHE_DIR points,
+else in `<repo>/.jax_cache` (`configure_compile_cache`).
+
+    PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b --reduced \\
+        --rounds 50 --local-steps 8 --agents 4 \\
         [--algorithm quantized_gt --quantization-bits 8] [--runtime async]
+    PYTHONPATH=src python -m repro.launch.train --arch granite-8b \\
+        --num-layers 1 --agents 2 --per-agent-batch 1 --seq-len 1024 \\
+        --local-steps 2 --rounds 3          # one TPU v5e, published widths
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+import pathlib
 import time
+from typing import Any, Callable, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..checkpoint import save_checkpoint
-from ..configs import get_config
+from ..configs import ModelConfig, get_config
 from ..core.engine import make_round
 from ..data import federated_token_batches
 from ..fed.strategies import resolve_strategy
@@ -53,11 +72,67 @@ from ..problems.adversarial import (
     make_adversarial_loss,
 )
 
+#: the fixed cache location when JAX_COMPILATION_CACHE_DIR is unset: the
+#: path is part of the cache key, so it never depends on a temp name
+REPO_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def main() -> None:
+
+class TrainResult(NamedTuple):
+    """What `main` hands back to a caller driving it in-process."""
+
+    params: Any
+    delta: Any
+    #: global loss after each logged round (`--log-every`, plus the last)
+    losses: List[float]
+    #: wall seconds per round; the fused sync loop ends each one with
+    #: `block_until_ready`, the runners time their own rounds (metric
+    #: evaluation included, the first round's compilation too)
+    round_seconds: List[float]
+    #: ahead-of-time compilation of the fused round (sync runtime only)
+    compile_seconds: Optional[float]
+    #: the resolved model config, per-agent loss and agent-stacked data,
+    #: so a caller can evaluate the final iterate on the same problem
+    cfg: ModelConfig
+    loss_fn: Callable
+    data: Any
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its directory, before
+    the first compile: JAX_COMPILATION_CACHE_DIR when set (JAX reads the
+    variable itself, so nothing is set here), else `REPO_CACHE_DIR`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_CACHE_DIR))
+    return str(REPO_CACHE_DIR)
+
+
+def build_fused_round(loss, strategy, local_steps: int, eta: float):
+    """The jitted fused round the sync runtime runs:
+    `(params, delta, data[, state]) -> (params, delta[, state])`, with
+    the iterates and the strategy state donated so the round's outputs
+    reuse their input buffers."""
+    stateful = strategy.stateful
+    return jax.jit(
+        make_round(
+            loss, strategy, local_steps, eta,
+            proj_y=delta_projection(1.0), explicit_state=stateful,
+        ),
+        donate_argnums=(0, 1, 3) if stateful else (0, 1),
+    )
+
+
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="depth cut: keep N layers, a whole number of "
+                         "periods of the layer pattern (widths unchanged)")
+    ap.add_argument("--vocab-size", type=int, default=None,
+                    help="the chip's share of the vocabulary: ids, logits "
+                         "and the loss are over the first V entries")
     ap.add_argument("--rounds", type=int, default=50)
     ap.add_argument("--local-steps", type=int, default=8)
     ap.add_argument("--agents", type=int, default=4)
@@ -130,7 +205,49 @@ def main() -> None:
                     help="comma-separated round indices to wrap in a "
                          "jax.profiler trace (written under "
                          "DIR/profile; requires --telemetry)")
-    args = ap.parse_args()
+    return ap
+
+
+def _resolve_config(ap: argparse.ArgumentParser, args) -> ModelConfig:
+    """The registry config with the requested cuts applied; a cut that
+    is not a share of the published model is a usage error."""
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    cuts = {}
+    if args.num_layers is not None:
+        period = len(cfg.pattern)
+        if args.num_layers < 1 or args.num_layers % period:
+            ap.error(
+                f"--num-layers {args.num_layers}: {cfg.name} needs a whole "
+                f"number of its {period}-layer pattern periods "
+                f"{cfg.pattern}"
+            )
+        cuts["num_layers"] = args.num_layers
+    if args.vocab_size is not None:
+        if not 1 <= args.vocab_size <= cfg.vocab_size:
+            ap.error(
+                f"--vocab-size {args.vocab_size}: must be a share of "
+                f"{cfg.name}'s vocabulary of {cfg.vocab_size}"
+            )
+        cuts["vocab_size"] = args.vocab_size
+    return dataclasses.replace(cfg, **cuts) if cuts else cfg
+
+
+def _describe(cfg: ModelConfig, published: ModelConfig, params) -> str:
+    return (
+        f"arch={cfg.name} d_model={cfg.d_model} heads={cfg.num_heads} "
+        f"kv_heads={cfg.num_kv_heads} head_dim={cfg.head_dim} "
+        f"d_ff={cfg.d_ff} num_layers={cfg.num_layers}/{published.num_layers} "
+        f"vocab={cfg.vocab_size}/{published.vocab_size} "
+        f"params={num_params(params)/1e6:.1f}M"
+    )
+
+
+def main(argv: Optional[List[str]] = None) -> TrainResult:
+    ap = _parser()
+    args = ap.parse_args(argv)
+    configure_compile_cache()
 
     from .multihost import init_distributed
 
@@ -155,14 +272,14 @@ def main() -> None:
         args.algorithm, **{k: v for k, v in knobs.items() if v is not None}
     )
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
+    cfg = _resolve_config(ap, args)
     key = jax.random.PRNGKey(0)
     params = init_params(key, cfg, jnp.float32)
     delta = init_delta(cfg)
-    print(f"arch={cfg.name} params={num_params(params)/1e6:.1f}M "
-          f"agents={args.agents} K={args.local_steps} algo={args.algorithm}")
+    print(_describe(cfg, get_config(args.arch), params))
+    print(f"agents={args.agents} per_agent_batch={args.per_agent_batch} "
+          f"seq_len={args.seq_len} K={args.local_steps} "
+          f"algo={args.algorithm} runtime={args.runtime}")
 
     data = federated_token_batches(
         jax.random.PRNGKey(1), args.agents, args.per_agent_batch,
@@ -175,6 +292,16 @@ def main() -> None:
         return jnp.mean(per)
 
     gl = jax.jit(global_loss)
+
+    def logged(t: int) -> bool:
+        return t % args.log_every == 0 or t == args.rounds - 1
+
+    def runner_result(runner, params, delta) -> TrainResult:
+        logged_stats = [h for h in runner.history if logged(h.round_index)]
+        return TrainResult(
+            params, delta, [h.metrics["loss"] for h in logged_stats],
+            [h.seconds for h in runner.history], None, cfg, loss, data,
+        )
 
     schedule = None
     rebase = not args.no_rebase
@@ -193,8 +320,6 @@ def main() -> None:
 
     telemetry = ledger = None
     if args.telemetry:
-        import os
-
         from ..obs import RunLedger, Telemetry, run_manifest
 
         ledger = RunLedger(args.telemetry)
@@ -239,7 +364,7 @@ def main() -> None:
         if ledger is not None:
             ledger.close()
         print("done.")
-        return
+        return runner_result(runner, params, delta)
 
     if schedule is not None:
         # elastic sync run: the runner owns the schedule threading
@@ -264,13 +389,9 @@ def main() -> None:
         if ledger is not None:
             ledger.close()
         print("done.")
-        return
+        return runner_result(runner, params, delta)
 
     stateful = strategy.stateful
-    rnd = jax.jit(make_round(
-        loss, strategy, args.local_steps, args.eta,
-        proj_y=delta_projection(1.0), explicit_state=stateful,
-    ))
     state = strategy.init_state(params, delta, args.agents) if stateful else None
     per_agent = None
     if telemetry is not None:
@@ -279,6 +400,14 @@ def main() -> None:
         per_agent = int(measured_bytes_per_round(
             strategy, params, delta, args.local_steps
         ))
+    carry = (params, delta, state) if stateful else (params, delta)
+    c0 = time.perf_counter()
+    rnd = build_fused_round(loss, strategy, args.local_steps, args.eta)
+    rnd = rnd.lower(*carry[:2], data, *carry[2:]).compile()
+    compile_seconds = time.perf_counter() - c0
+    print(f"compiled the fused round in {compile_seconds:.2f}s", flush=True)
+    losses: List[float] = []
+    round_seconds: List[float] = []
     t0 = time.time()
     for t in range(args.rounds):
         rt0 = time.perf_counter()
@@ -288,18 +417,20 @@ def main() -> None:
             params, delta, state = rnd(params, delta, data, state)
         else:
             params, delta = rnd(params, delta, data)
+        jax.block_until_ready(params)
+        round_seconds.append(time.perf_counter() - rt0)
         if telemetry is not None:
-            jax.block_until_ready(params)
             telemetry.round_event(
-                t, runtime="fused", seconds=time.perf_counter() - rt0
+                t, runtime="fused", seconds=round_seconds[-1]
             )
             telemetry.counter(
                 "wire_bytes", per_agent * args.agents,
                 per_agent=per_agent, n_active=args.agents,
             )
             telemetry.end_round(t)
-        if t % args.log_every == 0 or t == args.rounds - 1:
+        if logged(t):
             lv = float(gl(params, delta))
+            losses.append(lv)
             dn = float(jnp.linalg.norm(delta["delta"]))
             print(f"[round {t:4d}] loss={lv:.4f} |delta|={dn:.4f} "
                   f"({time.time()-t0:.1f}s)", flush=True)
@@ -313,6 +444,10 @@ def main() -> None:
     if ledger is not None:
         ledger.close()
     print("done.")
+    return TrainResult(
+        params, delta, losses, round_seconds, compile_seconds, cfg, loss,
+        data,
+    )
 
 
 if __name__ == "__main__":

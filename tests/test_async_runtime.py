@@ -78,6 +78,11 @@ class TestAsyncRunnerParity:
         )
         xa, ya = runner.run(x0, y0, ROUNDS)
         assert runner._n_shards == M  # one agent per emulated device
+        # identity-transform GT forms each correction on its shard; the
+        # compressors transform the whole stack server-side
+        assert runner._shard_corr is (
+            name in ("gradient_tracking", "partial_gt")
+        )
         np.testing.assert_allclose(
             np.asarray(xa), np.asarray(xs), rtol=1e-9, atol=1e-12
         )

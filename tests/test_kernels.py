@@ -67,7 +67,7 @@ class TestGtUpdate:
         z = {f"l{i}": jax.random.normal(ks[i], s) for i, s in enumerate(tree_shape)}
         g = {f"l{i}": jax.random.normal(ks[3 + i], s) for i, s in enumerate(tree_shape)}
         c = {f"l{i}": jax.random.normal(ks[6 + i], s) for i, s in enumerate(tree_shape)}
-        upd = make_gt_update_fn(interpret=True, use_kernel=True)
+        upd = make_gt_update_fn(use_kernel=True)
         got = upd(z, g, c, 1e-2, 1.0)
         for kname in z:
             want = ref.gt_update_ref(z[kname], g[kname], c[kname], 1e-2, 1.0)
@@ -148,7 +148,7 @@ class TestFlashAttention:
         q = jax.random.normal(kq, (B, S, H, hd), F32)
         k = jax.random.normal(kk, (B, S, KV, hd), F32)
         v = jax.random.normal(kv, (B, S, KV, hd), F32)
-        got = grouped_flash_attention(q, k, v, causal=True, interpret=True)
+        got = grouped_flash_attention(q, k, v, causal=True)
         assert got.shape == (B, S, H, hd)
         G = H // KV
         kt = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1)
@@ -197,7 +197,7 @@ class TestSsmScan:
         da = jax.nn.sigmoid(jax.random.normal(k1, (B, S, D, N))) * 0.9
         dbx = jax.random.normal(k2, (B, S, D, N)) * 0.1
         c = jax.random.normal(k3, (B, S, N))
-        got = batched_ssm_scan(da, dbx, c, chunk=32, interpret=True)
+        got = batched_ssm_scan(da, dbx, c, chunk=32)
         for b in range(B):
             want, _ = ref.ssm_scan_ref(da[b], dbx[b], c[b], jnp.zeros((D, N)))
             np.testing.assert_allclose(

@@ -195,10 +195,8 @@ class TestUnpackKernelMatchesReference:
 
         c, e, u_sel, u_rnd = _inputs((4, 256), F32, seed=5)
         spec = _spec(4, 256, 0.25, 8)
-        payload, resid = encode_leaf(
-            c, e, u_sel, u_rnd, spec, use_kernel=True, interpret=True
-        )
-        decoded = decode_leaf(payload, spec, use_kernel=True, interpret=True)
+        payload, resid = encode_leaf(c, e, u_sel, u_rnd, spec, use_kernel=True)
+        decoded = decode_leaf(payload, spec, use_kernel=True)
         chat, resid_dense = compress_correction_2d(
             c, e, u_sel, u_rnd, k=spec.k, bits=8, interpret=True
         )
@@ -256,9 +254,7 @@ class TestDispatcher:
         spec = dataclasses.replace(
             LeafSpec.build((C,), F32, 0.5, 8), rows=2
         )
-        fusedp, _ = tr.encode_leaf(
-            c, e, u_sel, u_rnd, spec, use_kernel=True, interpret=True
-        )
+        fusedp, _ = tr.encode_leaf(c, e, u_sel, u_rnd, spec, use_kernel=True)
         assert calls["kernel"] == (1 if fused else 0)
         plain, _ = tr.encode_leaf(c, e, u_sel, u_rnd, spec, use_kernel=False)
         for a, b in zip(fusedp, plain):

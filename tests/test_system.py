@@ -127,10 +127,7 @@ class TestStepBuildersOnHostMesh:
         cfg = _dc.replace(
             get_config("granite-8b").reduced(), quantization_bits=8
         )
-        try:
-            mesh = make_host_mesh(1, 1)
-        except AttributeError as e:  # pragma: no cover
-            pytest.skip(f"host mesh unavailable on this jax: {e}")
+        mesh = make_host_mesh(1, 1)
         shape = ShapeConfig("tiny_train", seq_len=32, global_batch=2, kind="train")
         with jax.set_mesh(mesh):
             jitted, specs_fn = build_train_step(
@@ -163,12 +160,7 @@ class TestStepBuildersOnHostMesh:
         cfg = _dc.replace(
             get_config("granite-8b").reduced(), quantization_bits=8
         )
-        if not hasattr(jax, "set_mesh"):  # pragma: no cover
-            pytest.skip("jax.set_mesh unavailable on this jax")
-        try:
-            mesh = make_host_mesh(1, 1)
-        except AttributeError as e:  # pragma: no cover
-            pytest.skip(f"host mesh unavailable on this jax: {e}")
+        mesh = make_host_mesh(1, 1)
         shape = ShapeConfig("tiny_train", seq_len=32, global_batch=2,
                             kind="train")
         with jax.set_mesh(mesh):
