@@ -25,7 +25,11 @@ dispatch phases separately — `repro.fed.async_runtime` drives per-agent-
 shard `broadcast`/`local_steps` programs on their own devices and splits
 `exchange_corrections` between the shards (anchor gradients) and the
 server (transform) — consume the same phase functions, so there is one
-oracle for the round math whatever the execution schedule.
+oracle for the round math whatever the execution schedule.  Each phase
+runs under `jax.named_scope(<its field name>)` and each loss-gradient
+call under `jax.named_scope("loss_grad")`: HLO metadata only, so every
+schedule's compiled program, and a profiler trace of it, can be split
+by phase (`chipbench/scopes.py`).
 
 The legacy constructors — `make_gda_step`, `make_local_sgda_round`,
 `make_fedgda_gt_round` — remain thin wrappers over this engine with the
@@ -43,6 +47,7 @@ the literal K-step schedule instead.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -280,6 +285,25 @@ class RoundPhases(NamedTuple):
     aggregate: Callable
 
 
+def _scoped(name: str, fn: Callable) -> Callable:
+    """`fn` with every operation it traces under `jax.named_scope(name)`:
+    HLO metadata only (`op_name` paths), so values and fusions are
+    unchanged, and a profiler trace of the compiled program can be split
+    by the name (`chipbench/scopes.py`)."""
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+
+    return scoped
+
+
+def _named_phases(*fns: Callable) -> RoundPhases:
+    """The four phases, each under the scope of its field name."""
+    return RoundPhases(*(_scoped(n, f) for n, f in zip(RoundPhases._fields, fns)))
+
+
 def _num_agents(agent_data: Pytree) -> int:
     return jax.tree.leaves(agent_data)[0].shape[0]
 
@@ -306,7 +330,7 @@ def make_phases(
         # There is no per-agent divergence to broadcast or correct, so
         # broadcast/exchange are identities and the whole round lives in
         # local_steps (each "local" step IS a global aggregate).
-        vg = jax.vmap(gfn, in_axes=(None, None, 0))
+        vg = _scoped("loss_grad", jax.vmap(gfn, in_axes=(None, None, 0)))
 
         def gda_step(x, y, agent_data, weights=None):
             g = vg(x, y, agent_data)
@@ -349,9 +373,9 @@ def make_phases(
         def aggregate(rs):
             return rs.x, rs.y, rs.state
 
-        return RoundPhases(broadcast, exchange_corrections, local_steps, aggregate)
+        return _named_phases(broadcast, exchange_corrections, local_steps, aggregate)
 
-    vgrad = jax.vmap(gfn, in_axes=(0, 0, 0))
+    vgrad = _scoped("loss_grad", jax.vmap(gfn, in_axes=(0, 0, 0)))
     use_corr = bool(getattr(strategy, "use_correction", False))
     cdt = getattr(strategy, "correction_dtype", None)
     # stochastic knobs — None / 0.0 are trace-time identities: the
@@ -359,7 +383,8 @@ def make_phases(
     # zeroed noise, no 0-scaled velocity — bitwise-pinned)
     noise = getattr(strategy, "noise", None)
     momentum = float(getattr(strategy, "momentum", 0.0) or 0.0)
-    nvgrad = make_noise_vgrad(gfn, noise) if noise is not None else None
+    nvgrad = (_scoped("loss_grad", make_noise_vgrad(gfn, noise))
+              if noise is not None else None)
     if momentum:
         # lazy: optim.momentum imports core — only the momentum round
         # needs the shared heavy-ball primitive
@@ -562,7 +587,7 @@ def make_phases(
         y1 = proj_y(agent_mean(rs.ys, rs.weights))
         return x1, y1, rs.state
 
-    return RoundPhases(broadcast, exchange_corrections, local_steps, aggregate)
+    return _named_phases(broadcast, exchange_corrections, local_steps, aggregate)
 
 
 def make_round(

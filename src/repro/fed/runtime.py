@@ -41,11 +41,9 @@ active-set-aware account `wire_report` prices), and sampled invariant
 probes (`repro.obs.probes`).  `telemetry=None` (the default) runs the
 pre-telemetry code verbatim — the sink lives entirely on the host, the
 jitted round programs never change, and iterates stay bitwise identical
-(tests/test_obs.py).  `Telemetry(phase_spans=True)` additionally lets a
-strategy-built sync runner dispatch the four engine phases as SEPARATE
-jitted programs for genuine per-phase wall-clock — matching the fused
-round to fp tolerance by the phases contract (tests/test_phases.py: the
-composition is the same math, only XLA's program partitioning differs).
+(tests/test_obs.py).  Per-phase device time comes from a profiler
+trace of the fused program itself, whose phases carry their names
+(`core.engine.make_phases`).
 """
 from __future__ import annotations
 
@@ -339,12 +337,9 @@ class FederatedRunner(RunnerHistoryMixin):
         #: repro.obs.Telemetry sink or None (None = pre-telemetry code
         #: verbatim); public so tests flip it on a compiled runner
         self.telemetry = telemetry
-        # set by from_strategy — feed the wire counters / probes and the
-        # lazily-jitted per-phase programs (Telemetry(phase_spans=True))
+        # set by from_strategy — feed the wire counters / probes
         self._loss: Optional[Callable] = None
         self._num_local_steps: Optional[int] = None
-        self._phase_factory: Optional[Callable] = None
-        self._phase_fns = None
         self._last_schedule = None
         # elastic (sim.RoundSchedule) support: the membership-aware round
         # round(x, y, data, state, tracker, weights, budgets, active)
@@ -379,7 +374,7 @@ class FederatedRunner(RunnerHistoryMixin):
         unified engine and wrap it in a runner."""
         import functools
 
-        from ..core.engine import make_phases, make_round
+        from ..core.engine import make_round
         from ..sim.elastic import init_tracker, make_elastic_round
         from .strategies import resolve_strategy
 
@@ -414,16 +409,6 @@ class FederatedRunner(RunnerHistoryMixin):
         )
         runner._loss = loss
         runner._num_local_steps = num_local_steps
-        # deferred: Telemetry(phase_spans=True) rebuilds the SAME phases
-        # as separate jitted programs (bitwise-identical to the fused
-        # round — tests/test_phases.py); nothing is traced until used
-        runner._phase_factory = functools.partial(
-            make_phases, loss, strategy, num_local_steps, eta_x, eta_y,
-            **{
-                k: v for k, v in round_kwargs.items()
-                if k in ("proj_x", "proj_y", "update_fn", "constrain_agents")
-            },
-        )
         return runner
 
     @property
@@ -475,24 +460,14 @@ class FederatedRunner(RunnerHistoryMixin):
             )
         tm = self.telemetry
         per_agent = None
-        round_dispatch = None
         if tm is not None:
             self._emit_wire_probe(tm, x, y)
             per_agent = self._wire_counter_args(x, y, scheduled=False)
-            if tm.phase_spans and self._phase_factory is not None:
-                round_dispatch = self._phase_round(tm)
         for t in range(num_rounds):
             t0 = time.perf_counter()
             if tm is not None:
                 tm.begin_round(t)
-            if round_dispatch is not None:
-                x, y, new_state = round_dispatch(
-                    x, y, self._agent_data,
-                    self._state if self._stateful else {},
-                )
-                if self._stateful:
-                    self._state = new_state
-            elif self._stateful:
+            if self._stateful:
                 x, y, self._state = self._round(
                     x, y, self._agent_data, self._state
                 )
@@ -609,43 +584,3 @@ class FederatedRunner(RunnerHistoryMixin):
         if self._stateful:
             self._state = state
         return x, y
-
-    def _phase_round(self, tm):
-        """The `Telemetry(phase_spans=True)` dispatch: the four engine
-        phases as SEPARATE jitted programs, each wrapped in a span and
-        blocked to completion so the span measures device time, not
-        async-dispatch time.  `RoundState` is a registered pytree, so
-        the phases cross jit boundaries directly; the composition is the
-        fused round's math (tests/test_phases.py pins separately-jitted
-        phases to the fused round at rtol 1e-12 — XLA partitions the
-        programs differently, so agreement is fp-level, not bitwise).
-        Lazily traced on first use: default-mode runners never pay for
-        it."""
-        if self._phase_factory is None:
-            raise ValueError(
-                "phase_spans needs a runner built via from_strategy"
-            )
-        if self._phase_fns is None:
-            phases = self._phase_factory()
-            # broadcast's keyword-only knobs carry `_UNSET` sentinel
-            # defaults (not jit-traceable) — bind the positional form
-            self._phase_fns = (
-                jax.jit(lambda x, y, d, s: phases.broadcast(x, y, d, s)),
-                jax.jit(phases.exchange_corrections),
-                jax.jit(phases.local_steps),
-                jax.jit(phases.aggregate),
-            )
-        bcast, exch, local, aggr = self._phase_fns
-
-        def dispatch(x, y, data, state):
-            with tm.span("broadcast"):
-                rs = jax.block_until_ready(bcast(x, y, data, state))
-            with tm.span("exchange_corrections"):
-                rs = jax.block_until_ready(exch(rs, data))
-            with tm.span("local_steps"):
-                rs = jax.block_until_ready(local(rs, data))
-            with tm.span("aggregate"):
-                out = jax.block_until_ready(aggr(rs))
-            return out
-
-        return dispatch

@@ -361,8 +361,8 @@ def main(argv: Optional[List[str]] = None) -> TrainResult:
             save_checkpoint(
                 args.ckpt_dir, args.rounds, {"x": params, "y": delta}
             )
-        if ledger is not None:
-            ledger.close()
+        if telemetry is not None:
+            telemetry.close()
         print("done.")
         return runner_result(runner, params, delta)
 
@@ -386,8 +386,8 @@ def main(argv: Optional[List[str]] = None) -> TrainResult:
             params, delta, args.rounds, log_every=args.log_every,
             schedule=schedule, rebase=rebase,
         )
-        if ledger is not None:
-            ledger.close()
+        if telemetry is not None:
+            telemetry.close()
         print("done.")
         return runner_result(runner, params, delta)
 
@@ -441,8 +441,8 @@ def main(argv: Optional[List[str]] = None) -> TrainResult:
                 # error-feedback buffers
                 payload["strategy_state"] = state
             save_checkpoint(args.ckpt_dir, t + 1, payload)
-    if ledger is not None:
-        ledger.close()
+    if telemetry is not None:
+        telemetry.close()
     print("done.")
     return TrainResult(
         params, delta, losses, round_seconds, compile_seconds, cfg, loss,

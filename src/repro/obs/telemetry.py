@@ -19,11 +19,10 @@ observability contract in tests/README.md):
             `core.engine.make_phases` (broadcast /
             exchange_corrections / local_steps / aggregate).  On the
             async runtimes a phase span measures dispatch + host time
-            (jax's async dispatch returns before the device finishes);
-            the sync runner can dispatch the four phases as separate
-            jitted programs (`phase_spans=True` — fp-tolerance-equal to
-            the fused round by the phases contract, tests/test_phases)
-            for genuine per-phase wall-clock.
+            (jax's async dispatch returns before the device finishes).
+            Device time per phase is in a profiler trace: the engine
+            names each phase inside the compiled program
+            (`jax.named_scope`), and `chipbench/scopes.py` reads it.
   counter  {"kind": "counter", "name": ..., "round": t, "value": n, ...}
            — wire bytes ("wire_bytes" with per_agent / n_active,
            "gathered_payload_bytes" on the multihost gather), peak
@@ -39,7 +38,13 @@ The `round` field defaults to the sink's `current_round`, set by
 `begin_round` — emitters deep inside a runner (a skipped shard, a wire
 gather) need no round plumbing.  Attach a `repro.obs.RunLedger` to
 stream every event to JSONL as it is emitted; `profile_rounds` wraps the
-listed rounds in a `jax.profiler` trace (written under `profile_dir`).
+listed rounds in a `jax.profiler` trace (written under `profile_dir`;
+consecutive rounds share one trace).  Spans and rounds are also on the
+profiler's clock: a span enters `jax.profiler.TraceAnnotation(name)`,
+and `begin_round` / `end_round` hold a
+`StepTraceAnnotation("round", step_num=t)`, so a trace shows the
+program's own spans beside the device's operations
+(`chipbench/trace.py load` reads them as host spans).
 """
 from __future__ import annotations
 
@@ -61,7 +66,6 @@ class Telemetry:
         ledger=None,
         probes: Sequence[str] = (),
         probe_every: int = 1,
-        phase_spans: bool = False,
         gap_fn: Optional[Callable] = None,
         profile_dir: Optional[str] = None,
         profile_rounds: Sequence[int] = (),
@@ -70,7 +74,6 @@ class Telemetry:
         self.ledger = ledger
         self.probes = frozenset(probes)
         self.probe_every = max(1, int(probe_every))
-        self.phase_spans = bool(phase_spans)
         #: duality-gap oracle for the "duality_gap" probe — supplied by
         #: the caller (the saddle point is problem knowledge, not ours)
         self.gap_fn = gap_fn
@@ -78,6 +81,7 @@ class Telemetry:
         self.profile_rounds = frozenset(int(r) for r in profile_rounds)
         self.current_round: Optional[int] = None
         self._profiling = False
+        self._round_annotation = None
 
     # ------------------------------------------------------------- emit
     def emit(self, kind: str, name: str, round: Optional[int] = None,
@@ -99,9 +103,12 @@ class Telemetry:
 
     @contextlib.contextmanager
     def span(self, name: str, round: Optional[int] = None, **attrs):
+        import jax
+
         t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
             self.emit("span", name, round=round,
                       seconds=time.perf_counter() - t0, **attrs)
@@ -115,13 +122,33 @@ class Telemetry:
 
     # ------------------------------------------------------------ rounds
     def begin_round(self, t: int) -> None:
+        import jax
+
         self.current_round = int(t)
         if self.profile_dir is not None and t in self.profile_rounds:
             self.start_profile()
+        self._close_round()
+        self._round_annotation = jax.profiler.StepTraceAnnotation(
+            "round", step_num=int(t))
+        self._round_annotation.__enter__()
 
     def end_round(self, t: int) -> None:
-        if self._profiling:
+        self._close_round()
+        if self._profiling and t + 1 not in self.profile_rounds:
             self.stop_profile()
+
+    def _close_round(self) -> None:
+        if self._round_annotation is not None:
+            self._round_annotation.__exit__(None, None, None)
+            self._round_annotation = None
+
+    def close(self) -> None:
+        """Stop a profile that the last round left open (its next round
+        was listed too) and close the ledger."""
+        self._close_round()
+        self.stop_profile()
+        if self.ledger is not None:
+            self.ledger.close()
 
     def start_profile(self) -> None:
         if self._profiling:

@@ -10,10 +10,9 @@ event schema):
     the sparse engine (dense-fallback and genuinely-sparse paths); the
     sink never enters a jitted program, so enabling it cannot perturb a
     single bit;
-  * `Telemetry(phase_spans=True)` dispatches the four engine phases as
-    separate jitted programs and matches the fused round at rtol 1e-12
-    (the phases contract — fp-level, not bitwise: XLA partitions the
-    programs differently);
+  * the sink's spans are on the profiler's clock: a `jax.profiler`
+    trace of an enabled run holds one `round` host span per round and
+    one span per phase dispatch, which `chipbench/trace.py load` reads;
   * the invariant probes are pure functions that read ~fp-reduction
     noise when the math is right: `gt_residual` over the tracker-table
     corrections, `tracker_drift` of the SparseTracker running sums, EF
@@ -89,8 +88,8 @@ x0 = jnp.ones(DIM)
 y0 = -jnp.ones(DIM)
 
 #: a sink with every emission path on: probes sampled each round, a gap
-#: oracle, phase bookkeeping — everything except phase_spans (its own
-#: fp-level test below) and a ledger (its own round-trip test below)
+#: oracle, phase bookkeeping — everything except a profiler trace (its
+#: own test below) and a ledger (its own round-trip test below)
 ALL_PROBES = (
     "gt_residual", "tracker_drift", "ef_residual", "priced_vs_measured",
     "duality_gap",
@@ -212,34 +211,62 @@ class TestBitwisePins:
         assert len(fb) == 1 and fb[0]["value"] is (True if fallback else False)
 
 
-# ----------------------------------------------------- phase-span dispatch
-class TestPhaseSpans:
-    def test_matches_fused_round_fp(self, prob):
-        """phase_spans=True re-dispatches the four phases as separate
-        jitted programs: rtol 1e-12 vs the fused round (the phases
-        contract, tests/test_phases.py), with one span per phase."""
-        K = 4
-        runner = FederatedRunner.from_strategy(
-            prob.loss, GradientTracking(), prob.agent_data, K, ETA
+# --------------------------------------------------- spans on the profiler
+def _trace_module():
+    """`chipbench.trace`, from the benchmark package beside `src/`."""
+    import pathlib
+    import sys
+
+    root = str(pathlib.Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench import trace
+
+    return trace
+
+
+PHASES = ("broadcast", "exchange_corrections", "local_steps", "aggregate")
+
+
+@pytest.mark.multihost
+class TestProfilerSpans:
+    def test_round_and_phase_spans_in_the_trace(self, prob, fed_devices,
+                                                tmp_path):
+        """Every round of an async run profiled: the trace's host spans
+        hold `round` once per round, in order, and each phase span of
+        the runtime once per round, inside its round."""
+        trace = _trace_module()
+        tm = Telemetry(profile_dir=str(tmp_path), profile_rounds=range(T))
+        runner = AsyncFederatedRunner(
+            prob.loss, GradientTracking(), prob.agent_data, 4, ETA,
+            devices=fed_devices, telemetry=tm,
         )
-        xa, ya = runner.run(x0, y0, T)
-        tm = Telemetry(phase_spans=True)
-        runner.telemetry = tm
-        xb, yb = runner.run(x0, y0, T)
-        np.testing.assert_allclose(np.asarray(xa), np.asarray(xb), rtol=1e-12)
-        np.testing.assert_allclose(np.asarray(ya), np.asarray(yb), rtol=1e-12)
-        for phase in ("broadcast", "exchange_corrections", "local_steps",
-                      "aggregate"):
-            assert len(tm.series("span", phase)) == T
+        runner.run(x0, y0, T)
+        tm.close()
+        host = trace.load(trace.find_xplane(str(tmp_path)))["host"]
+        rounds = sorted((s, s + d) for n, s, d in host if n == "round")
+        assert len(rounds) == T
+        assert len(tm.series("event", "profile_trace")) == 1
+        for phase in PHASES:
+            spans = [(s, s + d) for n, s, d in host if n == phase]
+            assert len(spans) == T == len(tm.series("span", phase))
+            for (a, b), (lo, hi) in zip(sorted(spans), rounds):
+                assert lo <= a <= b <= hi
 
-    def test_needs_strategy_built_runner(self, prob):
-        from repro.core import make_round
-
-        rnd = make_round(prob.loss, GradientTracking(), 2, ETA)
-        runner = FederatedRunner(rnd, prob.agent_data)
-        runner.telemetry = Telemetry(phase_spans=True)
-        with pytest.raises(ValueError, match="from_strategy"):
-            runner._phase_round(runner.telemetry)
+    def test_consecutive_rounds_share_one_trace(self, prob, tmp_path):
+        """Rounds 1 and 2 of 4 profiled: one trace holding their two
+        `round` spans, stopped after round 2."""
+        trace = _trace_module()
+        tm = Telemetry(profile_dir=str(tmp_path), profile_rounds=(1, 2))
+        runner = FederatedRunner.from_strategy(
+            prob.loss, GradientTracking(), prob.agent_data, 2, ETA,
+            telemetry=tm,
+        )
+        runner.run(x0, y0, 4)
+        assert not tm._profiling
+        assert len(tm.series("event", "profile_trace")) == 1
+        host = trace.load(trace.find_xplane(str(tmp_path)))["host"]
+        assert [n for n, _, _ in host if n == "round"] == ["round"] * 2
 
 
 # ------------------------------------------------------------- probe units

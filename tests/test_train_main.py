@@ -39,6 +39,23 @@ def test_main_returns_finite_falling_losses(runtime, cache_env):
         assert res.compile_seconds > 0
 
 
+def test_profile_rounds_trace_holds_a_round_span_each(cache_env, tmp_path):
+    """`--telemetry DIR --profile-rounds 1,2`: one trace under DIR/profile
+    whose host spans hold one `round` per profiled round, found by the
+    benchmark's trace reader."""
+    import sys
+
+    if str(REPO) not in sys.path:
+        sys.path.insert(0, str(REPO))
+    from chipbench import trace
+
+    d = tmp_path / "telemetry"
+    train.main(["--arch", "granite-8b", "--reduced", "--rounds", "4",
+                "--telemetry", str(d), "--profile-rounds", "1,2"] + TINY)
+    host = trace.load(trace.find_xplane(str(d / "profile")))["host"]
+    assert [n for n, _, _ in host if n == "round"] == ["round"] * 2
+
+
 def test_cuts_keep_widths(cache_env):
     res = train.main(["--arch", "granite-8b", "--reduced", "--rounds", "1",
                       "--num-layers", "3", "--vocab-size", "100"] + TINY)
