@@ -16,7 +16,7 @@ from repro.kernels import (
     gt_update_2d,
     pack_payload_2d,
     ref,
-    ssm_scan,
+    selective_scan,
 )
 
 from .common import emit, timed
@@ -86,20 +86,21 @@ def run(rows=None):
         "ref_us_per_call": f"{timed(lambda: rfn(q, k_, v).block_until_ready()):.0f}",
     })
 
-    # ssm scan: falcon-mamba-like tile
-    k1, k2, k3 = jax.random.split(key, 3)
+    # selective scan: falcon-mamba-like tile (state 16), from a zero state
+    k1, k2, k3, k4 = jax.random.split(key, 4)
     S, D, N = 256, 256, 16
-    da = jax.nn.sigmoid(jax.random.normal(k1, (S, D, N))) * 0.95
-    dbx = jax.random.normal(k2, (S, D, N)) * 0.1
-    cc = jax.random.normal(k3, (S, N))
-    got = ssm_scan(da, dbx, cc, chunk=64, interpret=True)
-    want, _ = ref.ssm_scan_ref(da, dbx, cc, jnp.zeros((D, N)))
-    rfn = jax.jit(lambda a, b, d: ref.ssm_scan_ref(a, b, d, jnp.zeros((D, N)))[0])
-    rfn(da, dbx, cc).block_until_ready()
+    dt = jax.nn.softplus(jax.random.normal(k1, (1, S, D)) - 2.0)
+    x = jax.random.normal(k2, (1, S, D))
+    A = -jnp.broadcast_to(jnp.arange(1.0, N + 1), (D, N))
+    bc, cc = (jax.random.normal(kk, (1, S, N)) for kk in (k3, k4))
+    got = selective_scan(dt, x, A, bc, cc, interpret=True)
+    want, _ = ref.ssm_scan_ref(dt, x, A, bc, cc)
+    rfn = jax.jit(lambda *a: ref.ssm_scan_ref(*a)[0])
+    rfn(dt, x, A, bc, cc).block_until_ready()
     rows.append({
-        "kernel": "ssm_scan(S256 D256 N16, chunk=64)",
+        "kernel": "selective_scan(S256 D256 N16)",
         "max_abs_err_vs_ref": f"{float(jnp.max(jnp.abs(got - want))):.2e}",
-        "ref_us_per_call": f"{timed(lambda: rfn(da, dbx, cc).block_until_ready()):.0f}",
+        "ref_us_per_call": f"{timed(lambda: rfn(dt, x, A, bc, cc).block_until_ready()):.0f}",
     })
 
     emit(rows, ["kernel", "max_abs_err_vs_ref", "ref_us_per_call"],

@@ -11,7 +11,8 @@ substrate:
                            sparse wire format (and the fused unpack+
                            dequant+scatter-add inverse) for fed.transport
   * flash_attention — blocked online-softmax attention (causal/window/softcap)
-  * ssm_scan        — chunked Mamba selective scan with VMEM-carried state
+  * selective_scan  — fused Mamba-1 selective scan with its own backward,
+                      the state carried in VMEM (models/mamba.py on a TPU)
 """
 from .gt_update import gt_update_2d
 from .compress_correction import (
@@ -21,9 +22,8 @@ from .compress_correction import (
 )
 from .pack_payload import pack_payload_2d, unpack_payload_2d
 from .flash_attention import flash_attention
-from .ssm_scan import ssm_scan
+from .ssm_scan import selective_scan
 from .ops import (
-    batched_ssm_scan,
     grouped_flash_attention,
     make_gt_update_fn,
 )
@@ -37,8 +37,7 @@ __all__ = [
     "pack_payload_2d",
     "unpack_payload_2d",
     "flash_attention",
-    "ssm_scan",
-    "batched_ssm_scan",
+    "selective_scan",
     "grouped_flash_attention",
     "make_gt_update_fn",
     "ref",

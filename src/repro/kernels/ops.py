@@ -6,7 +6,6 @@ False)` falls back to the ref oracle.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -15,7 +14,6 @@ import jax.numpy as jnp
 from . import ref
 from .flash_attention import flash_attention
 from .gt_update import gt_update_2d
-from .ssm_scan import ssm_scan
 
 Pytree = Any
 
@@ -74,13 +72,3 @@ def grouped_flash_attention(
     )
     return out.transpose(0, 2, 1, 3)
 
-
-def batched_ssm_scan(
-    da: jax.Array,  # [B, S, D, N]
-    dbx: jax.Array,
-    c_coef: jax.Array,  # [B, S, N]
-    *,
-    chunk: int = 64,
-) -> jax.Array:
-    fn = functools.partial(ssm_scan, chunk=chunk)
-    return jax.vmap(fn)(da, dbx, c_coef)

@@ -283,17 +283,22 @@ def flash_attention_ref(
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
-def ssm_scan_ref(da, dbx, c_coef, state0):
-    """Sequential oracle of h_t = da_t * h_{t-1} + dbx_t;  y_t = <h_t, c_t>.
+def ssm_scan_ref(dt, x, A, Bc, Cc, state0=None):
+    """Sequential oracle of the selective scan
+    h_t = exp(dt_t A) * h_{t-1} + (dt_t x_t) B_t;  y_t = <h_t, C_t>.
 
-    da  [S, d, N] (broadcastable), dbx [S, d, N], c_coef [S, N],
-    state0 [d, N].  Returns (y [S, d], final_state [d, N]).
+    dt, x [Bt, S, D], A [D, N], Bc, Cc [Bt, S, N]; state0 [Bt, D, N]
+    (zeros when None).  Returns (y [Bt, S, D], final state [Bt, D, N]).
     """
+    if state0 is None:
+        state0 = jnp.zeros((dt.shape[0],) + A.shape, dt.dtype)
 
     def step(h, inp):
-        a, b, cc = inp
-        h = a * h + b
-        return h, jnp.einsum("dn,n->d", h, cc)
+        dt_t, x_t, b_t, c_t = inp
+        h = (jnp.exp(dt_t[..., None] * A) * h
+             + (dt_t * x_t)[..., None] * b_t[:, None, :])
+        return h, jnp.sum(h * c_t[:, None, :], axis=-1)
 
-    state, y = jax.lax.scan(step, state0, (da, dbx, c_coef))
-    return y, state
+    seq = tuple(jnp.swapaxes(u, 0, 1) for u in (dt, x, Bc, Cc))
+    state, y = jax.lax.scan(step, state0, seq)
+    return jnp.swapaxes(y, 0, 1), state

@@ -106,6 +106,21 @@ def _resolve_cfg_strategy(cfg: ModelConfig, algorithm) -> CommStrategy:
     return resolve_strategy(algorithm, **kw)
 
 
+def _traced_on(mesh, fn: Callable) -> Callable:
+    """`fn` traced under `mesh`'s abstract mesh, so that what it calls
+    sees that the program is partitioned over the mesh: the model keeps
+    its jnp paths there in place of a Pallas kernel that cannot be
+    partitioned automatically (`kernels.backend.on_one_tpu`).  The mesh
+    context changes nothing else in the lowered program."""
+
+    @functools.wraps(fn)
+    def traced(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args)
+
+    return traced
+
+
 def build_train_step(
     cfg: ModelConfig,
     mesh,
@@ -139,7 +154,7 @@ def build_train_step(
     constrain = make_agent_constraint(cfg, mesh, None, sharding_variant)
     strategy = _resolve_cfg_strategy(cfg, algorithm)
     stateful = strategy.stateful
-    rnd = make_round(
+    rnd = _traced_on(mesh, make_round(
         loss,
         strategy,
         num_local_steps,
@@ -147,7 +162,7 @@ def build_train_step(
         proj_y=proj_y,
         constrain_agents=constrain,
         explicit_state=stateful,
-    )
+    ))
 
     x_sh = param_shardings(abstract_params(cfg, dtype), cfg, mesh, sharding_variant)
     y_sh = jax.tree.map(lambda _: replicated(mesh), delta_struct(cfg, dtype))
@@ -225,14 +240,14 @@ def build_elastic_train_step(
     proj_y = delta_projection(delta_radius)
     constrain = make_agent_constraint(cfg, mesh, None, sharding_variant)
     strategy = _resolve_cfg_strategy(cfg, algorithm)
-    rnd = make_elastic_round(
+    rnd = _traced_on(mesh, make_elastic_round(
         loss,
         strategy,
         num_local_steps,
         eta,
         proj_y=proj_y,
         constrain_agents=constrain,
-    )
+    ))
 
     m = num_agents(mesh, cfg.fed_mode)
     fa = fed_axes(mesh, cfg.fed_mode)

@@ -4,10 +4,17 @@ Unified state layout [B, n_heads, head_p, d_state]:
   * mamba1: n_heads = d_inner, head_p = 1, A in R^{d_inner x N} (per-channel).
   * mamba2: n_heads = d_inner/head_p, A scalar per head.
 
-The sequence scan is CHUNKED: an associative scan runs inside fixed-size
-chunks (VMEM-sized working set — the same blocking the Pallas `ssm_scan`
-kernel uses) while a lax.scan carries the [B, nh, p, N] state across chunks.
-This bounds live memory to O(B * chunk * d_inner * N) instead of O(B * S * d_inner * N).
+The jnp sequence scan is CHUNKED: an associative scan runs inside
+fixed-size chunks (`ModelConfig.ssm_chunk`) while a lax.scan carries the
+[B, nh, p, N] state across chunks.  This bounds live memory to
+O(B * chunk * d_inner * N) instead of O(B * S * d_inner * N).
+
+Built for one TPU device (`kernels.backend.on_one_tpu`), a mamba1 block
+without a cache whose shape tiles (`kernels.ssm_scan.fits`) runs the
+fused Pallas `selective_scan` instead, forward and backward, which never
+writes a [S, d_inner, N] tensor to HBM.  Every other platform, a program
+partitioned over a mesh of TPUs, mamba2, the decode step and the
+cache-prefixed prefill take the jnp scan.
 """
 from __future__ import annotations
 
@@ -15,6 +22,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ..kernels import backend, ssm_scan
 
 
 def init_mamba(
@@ -90,6 +99,30 @@ def _chunked_scan(da, dbx, state, chunk):
     return hs.swapaxes(0, 1).reshape(B, S, *dbx.shape[2:]), state
 
 
+def _scan_states(da, dbx, state0, chunk):
+    """The jnp scan: states hs [B,S,nh,p,N] from state0, one step at
+    S == 1, else chunked; and the final state."""
+    if dbx.shape[1] == 1:
+        h1 = da[:, 0] * state0 + dbx[:, 0]
+        return h1[:, None], h1
+    return _chunked_scan(
+        da, dbx.astype(jnp.float32), state0, min(chunk, dbx.shape[1])
+    )
+
+
+def _mamba1_scan(dt, x, A, Bc, Cc, *, state0, chunk):
+    """The jnp mamba1 scan: y [B,S,di] float32 and the final state."""
+    B, S, d_inner = x.shape
+    d_state = A.shape[1]
+    da = jnp.exp(dt.astype(jnp.float32)[..., None] * A)  # [B,S,di,N]
+    da = da[..., None, :].reshape(B, S, d_inner, 1, d_state)
+    dbx = dt[..., None] * x[..., None] * Bc[:, :, None, :]  # [B,S,di,N]
+    dbx = dbx.reshape(B, S, d_inner, 1, d_state)
+    hs, state = _scan_states(da, dbx, state0, chunk)
+    y = jnp.einsum("bsnpN,bsN->bsnp", hs, Cc.astype(jnp.float32))
+    return y.reshape(B, S, d_inner), state
+
+
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     """Depthwise causal conv; x [B,S,di], w [W,di]."""
     W = w.shape[0]
@@ -150,20 +183,24 @@ def mamba_block(
         x = _causal_conv(x, params["conv_w"], params["conv_b"])
     x = jax.nn.silu(x)
 
+    state0 = (
+        cache["ssm"]
+        if cache is not None
+        else jnp.zeros((B, nh, p_dim, d_state), jnp.float32)
+    )
     if variant == "mamba1":
         dbl = x @ params["x_proj"]
         dt_rank = params["dt_proj"].shape[0]
         dt_raw, Bc, Cc = jnp.split(dbl, [dt_rank, dt_rank + d_state], axis=-1)
         dt = jax.nn.softplus(dt_raw @ params["dt_proj"] + params["dt_bias"])
         A = -jnp.exp(params["A_log"])  # [di, N]
-        da = jnp.exp(
-            dt.astype(jnp.float32)[..., None] * A
-        )  # [B,S,di,N]
-        da = da[..., None, :].reshape(B, S, nh, 1, d_state)
-        dbx = (
-            dt[..., None] * x[..., None] * Bc[:, :, None, :]
-        )  # [B,S,di,N]
-        dbx = dbx.reshape(B, S, nh, 1, d_state)
+        if (cache is None and backend.on_one_tpu()
+                and ssm_scan.fits(S, d_inner)):
+            y, state = ssm_scan.selective_scan(dt, x, A, Bc, Cc), None
+        else:
+            y, state = _mamba1_scan(
+                dt, x, A, Bc, Cc, state0=state0, chunk=chunk
+            )
     else:  # mamba2
         bcd = u @ params["bcdt_proj"]
         Bc, Cc, dt_raw = jnp.split(bcd, [d_state, 2 * d_state], axis=-1)
@@ -172,24 +209,7 @@ def mamba_block(
         da = jnp.exp(dt.astype(jnp.float32) * A)[..., None, None]  # [B,S,nh,1,1]
         xh = x.reshape(B, S, nh, head_p)
         dbx = (dt[..., None] * xh)[..., None] * Bc[:, :, None, None, :]
-
-    state0 = (
-        cache["ssm"]
-        if cache is not None
-        else jnp.zeros((B, nh, p_dim, d_state), jnp.float32)
-    )
-    if S == 1:
-        h1 = da[:, 0] * state0 + dbx[:, 0]
-        hs, state = h1[:, None], h1
-    else:
-        hs, state = _chunked_scan(
-            da, dbx.astype(jnp.float32), state0, min(chunk, S)
-        )
-
-    if variant == "mamba1":
-        y = jnp.einsum("bsnpN,bsN->bsnp", hs, Cc.astype(jnp.float32))
-        y = y.reshape(B, S, d_inner)
-    else:
+        hs, state = _scan_states(da, dbx, state0, chunk)
         y = jnp.einsum("bsnpN,bsN->bsnp", hs, Cc.astype(jnp.float32))
         y = y.reshape(B, S, d_inner)
     y = y.astype(u.dtype) + params["D"] * x.reshape(B, S, d_inner)

@@ -6,14 +6,15 @@ import numpy as np
 import pytest
 
 from repro.kernels import (
-    batched_ssm_scan,
+    backend,
     flash_attention,
     grouped_flash_attention,
     gt_update_2d,
     make_gt_update_fn,
     ref,
-    ssm_scan,
+    selective_scan,
 )
+from repro.models.mamba import _mamba1_scan, init_mamba, mamba_block
 
 pytestmark = pytest.mark.kernel  # Pallas interpret-mode suite
 
@@ -161,45 +162,126 @@ class TestFlashAttention:
         )
 
 
-# ---------------------------------------------------------------- ssm_scan
-class TestSsmScan:
+# ---------------------------------------------------------- selective_scan
+def _scan_inputs(seed, Bt, S, D, N):
+    """Mamba-1-like inputs: dt = softplus(.) > 0, A = -exp(A_log) < 0."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    dt = jax.nn.softplus(jax.random.normal(k[0], (Bt, S, D), F32) - 1.0)
+    x = jax.random.normal(k[1], (Bt, S, D), F32)
+    A = -jnp.exp(0.5 * jax.random.normal(k[2], (D, N), F32))
+    Bc = jax.random.normal(k[3], (Bt, S, N), F32)
+    Cc = jax.random.normal(k[4], (Bt, S, N), F32)
+    return dt, x, A, Bc, Cc
+
+
+def _jnp_scan(dt, x, A, Bc, Cc, chunk=32):
+    """The model's jnp path (`models/mamba._chunked_scan`), zero state."""
+    state0 = jnp.zeros((dt.shape[0], A.shape[0], 1, A.shape[1]), F32)
+    return _mamba1_scan(dt, x, A, Bc, Cc, state0=state0, chunk=chunk)[0]
+
+
+def _grads(fn, args, seed=7):
+    """Gradients of <fn(args), w> in all five inputs for a fixed random w."""
+    w = jax.random.normal(jax.random.PRNGKey(seed), args[0].shape, F32)
+    return jax.grad(lambda *a: jnp.sum(fn(*a) * w), argnums=range(5))(*args)
+
+
+def _close(got, want, tol=2e-5):
+    """Equal to `tol` of the largest entry of `want`."""
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=0, atol=tol * scale
+    )
+
+
+class TestSelectiveScan:
     @pytest.mark.parametrize("S,D,N", [(64, 128, 16), (128, 128, 8), (256, 256, 16)])
     @pytest.mark.parametrize("chunk", [32, 64])
     def test_matches_ref(self, S, D, N, chunk):
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
-        # decay in (0, 1) for stability, like exp(-softplus) in mamba
-        da = jax.nn.sigmoid(jax.random.normal(k1, (S, D, N))) * 0.95
-        dbx = jax.random.normal(k2, (S, D, N)) * 0.1
-        c = jax.random.normal(k3, (S, N))
-        got = ssm_scan(da, dbx, c, chunk=chunk, interpret=True)
-        want, _ = ref.ssm_scan_ref(da, dbx, c, jnp.zeros((D, N)))
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4
-        )
+        """Forward parity with the sequential oracle and the jnp path."""
+        args = _scan_inputs(0, 1, S, D, N)
+        got = selective_scan(*args, chunk=chunk, interpret=True)
+        want, _ = ref.ssm_scan_ref(*args)
+        assert got.shape == (1, S, D) and got.dtype == F32
+        _close(got, want)
+        _close(got, _jnp_scan(*args))
 
-    def test_chunk_invariance(self):
-        """Carried state across chunk boundaries: result must not depend on
-        the chunk size."""
-        S, D, N = 128, 128, 16
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(1), 3)
-        da = jax.nn.sigmoid(jax.random.normal(k1, (S, D, N))) * 0.9
-        dbx = jax.random.normal(k2, (S, D, N)) * 0.1
-        c = jax.random.normal(k3, (S, N))
-        y32 = ssm_scan(da, dbx, c, chunk=32, interpret=True)
-        y128 = ssm_scan(da, dbx, c, chunk=128, interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(y32), np.asarray(y128), rtol=1e-5, atol=1e-5
-        )
+    @pytest.mark.parametrize("S,D,chunk,block_d", [
+        (64, 256, 16, 128),   # 4 chunks, 2 d-blocks
+        (96, 256, 32, 256),   # 3 chunks, 1 d-block
+    ])
+    def test_gradients_match_jnp_path(self, S, D, chunk, block_d):
+        """d/d(dt, x, A, B, C) of the custom VJP against jax.grad of the
+        model's jnp scan, across several chunks and d-blocks."""
+        args = _scan_inputs(1, 2, S, D, 16)
+        got = _grads(lambda *a: selective_scan(
+            *a, chunk=chunk, block_d=block_d, interpret=True), args)
+        want = _grads(_jnp_scan, args)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape
+            _close(g, w, tol=5e-5)
 
-    def test_batched_wrapper(self):
-        B, S, D, N = 2, 64, 128, 8
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(2), 3)
-        da = jax.nn.sigmoid(jax.random.normal(k1, (B, S, D, N))) * 0.9
-        dbx = jax.random.normal(k2, (B, S, D, N)) * 0.1
-        c = jax.random.normal(k3, (B, S, N))
-        got = batched_ssm_scan(da, dbx, c, chunk=32)
-        for b in range(B):
-            want, _ = ref.ssm_scan_ref(da[b], dbx[b], c[b], jnp.zeros((D, N)))
-            np.testing.assert_allclose(
-                np.asarray(got[b]), np.asarray(want), rtol=1e-4, atol=1e-4
-            )
+    @staticmethod
+    def _tiled(chunk, block_d, args):
+        f = lambda *a: selective_scan(  # noqa: E731
+            *a, chunk=chunk, block_d=block_d, interpret=True)
+        return (f(*args),) + _grads(f, args)
+
+    @pytest.fixture(scope="class")
+    def one_tile(self):
+        """Inputs, and y with every gradient under one chunk and one
+        d-block."""
+        args = _scan_inputs(2, 1, 64, 256, 16)
+        return args, self._tiled(64, 256, args)
+
+    @pytest.mark.parametrize("chunk,block_d", [
+        (16, 128), (32, 256), (64, 128),
+    ])
+    def test_chunk_invariance(self, chunk, block_d, one_tile):
+        """The carried state and adjoint across chunk and d-block
+        boundaries: y and every gradient match the one-chunk, one-block
+        tiling."""
+        args, want = one_tile
+        for g, w in zip(self._tiled(chunk, block_d, args), want):
+            _close(g, w, tol=1e-5)
+
+    def test_mamba_block_takes_the_kernel(self, monkeypatch):
+        """A mamba1 block built as for one TPU runs the kernel (here
+        interpreted) in place of the jnp scan: the same output and the
+        same parameter gradients."""
+        params = init_mamba(jax.random.PRNGKey(3), 64, 128, 16, 4,
+                            "mamba1", F32)
+        u = jax.random.normal(jax.random.PRNGKey(4), (2, 16, 64), F32)
+        w = jax.random.normal(jax.random.PRNGKey(5), u.shape, F32)
+
+        def loss(p):
+            out, cache = mamba_block(p, u, variant="mamba1", d_state=16)
+            assert cache is None
+            return jnp.sum(out * w)
+
+        def run():
+            jaxpr = str(jax.make_jaxpr(jax.grad(loss))(params))
+            return ("pallas_call" in jaxpr, jax.value_and_grad(loss)(params))
+
+        jnp_path, (want, gwant) = run()
+        monkeypatch.setattr(backend, "on_one_tpu", lambda: True)
+        kernel, (got, ggot) = run()
+        assert kernel and not jnp_path
+        _close(got, want)
+        for k in gwant:
+            _close(ggot[k], gwant[k], tol=5e-5)
+
+    def test_vmap_over_agents(self):
+        """The round's vmap(grad) over a leading agent axis: the batched
+        kernels (an extra grid axis) equal one call per agent."""
+        m = 2
+        args = [jnp.stack(u) for u in zip(
+            *(_scan_inputs(10 + i, 1, 32, 128, 16) for i in range(m)))]
+        f = lambda *a: selective_scan(*a, chunk=16, interpret=True)  # noqa: E731
+        y = jax.vmap(f)(*args)
+        g = jax.vmap(lambda *a: _grads(f, a))(*args)
+        for i in range(m):
+            one = [u[i] for u in args]
+            _close(y[i], f(*one))
+            for gb, g1 in zip(g, _grads(f, one)):
+                _close(gb[i], g1, tol=1e-6)
