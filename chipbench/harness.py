@@ -10,7 +10,6 @@ called once per round, each round ending in `block_until_ready`.
 """
 from __future__ import annotations
 
-import argparse
 import gc
 import shutil
 import sys
@@ -68,25 +67,15 @@ def memory_peak_bytes(stats: Optional[Dict]) -> Optional[int]:
 # ------------------------------------------------------------ the program
 def program_config(c: Dict):
     """The program's model config for configuration file `c`, cut as the
-    file says; a width that differs from the file is an error."""
+    file says, parsed and resolved as `train.main` does it from the
+    arguments that the file's architecture module gives; a field that
+    differs from the file is an error."""
     from repro.launch import train
 
-    ns = argparse.Namespace(arch=c["arch"], reduced=c.get("preset") == "reduced",
-                            num_layers=c["num_hidden_layers"],
-                            vocab_size=c["vocab_size"])
-    cfg = train._resolve_config(train._parser(), ns)
-    want = {"d_model": c["hidden_size"], "num_layers": c["num_hidden_layers"],
-            "vocab_size": c["vocab_size"], "pattern": tuple(c["layer_pattern"])}
-    if c["layer_pattern"] == ["attn"]:
-        want.update(num_heads=c["num_attention_heads"],
-                    num_kv_heads=c["num_key_value_heads"],
-                    head_dim=c["head_dim"], d_ff=c["intermediate_size"],
-                    rope_theta=c["rope_theta"])
-    else:
-        want.update(d_inner=c["intermediate_size"], ssm_state=c["state_size"],
-                    conv_width=c["conv_kernel"])
-        if c["time_step_rank"] != max(1, cfg.d_model // 16):
-            raise ValueError("time_step_rank differs from the program's d_model // 16")
+    arch = registry.arch(c["arch"])
+    ap = train._parser()
+    cfg = train._resolve_config(ap, ap.parse_args(arch.program_args(c)))
+    want = arch.program_fields(c)
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise ValueError(f"program config {got} differs from the file's {want}")

@@ -5,10 +5,19 @@
 - correctness limits of cell `<w>`: `chipbench/limits/<w>.json`;
 - metric `<m>`: module `chipbench/metrics/<m>.py`, whose `read(ctx)`
   returns the number or None where the run has nothing to read; a cell
-  reports the metrics whose readers find something in it.
+  reports the metrics whose readers find something in it;
+- architecture `<a>`, the `arch` key of a configuration file: module
+  `chipbench/archs/<a>.py`, everything of the benchmark that knows the
+  model.  It gives `program_args(c)`, the `launch/train.py` arguments
+  that cut the program to the file; `program_fields(c)`, the program's
+  `ModelConfig` fields as the file states them; `init_params(key, c,
+  dtype)` and `agent_loss(params, delta, batch, c)`, the plain float32
+  reference model; and `forward_flops(c, batch, seq, *, causal_half)`,
+  its forward FLOPs split into `matmul` and `elementwise`.
+  `chipbench/archs/common.py` holds the parts they share.
 
-A later cell, configuration or metric is a new file and a new entry in
-`BENCHMARK.json`; nothing here changes.
+A later cell, configuration, architecture or metric is a new file and a
+new entry in `BENCHMARK.json`; nothing here changes.
 """
 from __future__ import annotations
 
@@ -19,6 +28,7 @@ from typing import Dict, List
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
+ARCHS = HERE / "archs"
 
 
 def load_benchmark(root: pathlib.Path = ROOT) -> Dict:
@@ -44,14 +54,24 @@ def limits(workload: str) -> Dict[str, float]:
     return _json("limits", workload)
 
 
-def metric(name: str):
-    path = HERE / "metrics" / f"{name}.py"
+def _module(path: pathlib.Path, what: str, module_name: str):
+    """The module at `path`, loaded by path so that hyphenated names work."""
     if not path.is_file():
-        raise FileNotFoundError(f"no metric reader {path}")
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
+        raise FileNotFoundError(f"no {what} {path}")
+    spec = importlib.util.spec_from_file_location(module_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def metric(name: str):
+    return _module(HERE / "metrics" / f"{name}.py", "metric reader",
+                   f"chipbench_metric_{name}")
+
+
+def arch(name: str):
+    return _module(ARCHS / f"{name}.py", "architecture module",
+                   f"chipbench_arch_{name}")
 
 
 def cell(bench: Dict, workload: str) -> Dict:
