@@ -105,3 +105,32 @@ def forward_flops(c: Dict, batch: int, seq: int, *,
     mm = L * (proj + readout) + common.lm_head_flops(c, batch, seq)
     ew = L * (2 * T * di * W + 4 * T * di * N)
     return {"matmul": float(mm), "elementwise": float(ew)}
+
+
+def kernel_bytes(c: Dict, traffic: Dict) -> Dict[str, float]:
+    """HBM bytes per round that the selective-scan kernels
+    (`kernels/ssm_scan.py selective_scan`: `ssm_scan_fwd`, and
+    `ssm_scan_bwd` for its gradient) cannot do without, in float32:
+    every operand read once and every result written once.  A gradient
+    evaluation makes one call of each per layer over the rows of all m
+    agents; a round makes K evaluations (`flops.round_flops`).
+
+    - forward: dt, x [rows, S, d_inner], A [d_inner, N] (once a call:
+      the agents may share it), B, C [rows, S, N] in; y [rows, S,
+      d_inner] out;
+    - backward: the forward's inputs and dy in; ddt, dx, dB, dC and dA,
+      one [d_inner, N] per agent, out.
+
+    The state entering each time chunk, which the forward saves for the
+    backward, and the backward's partial sums of dB and dC per block of
+    channels depend on the kernels' tiling, and are left out: the count
+    holds for any tiling, so the share of the bandwidth peak it gives
+    cannot pass 1."""
+    m, K = traffic["agents"], traffic["local_steps"]
+    T = m * traffic["per_agent_batch"] * traffic["seq_len"]
+    di, N = c["intermediate_size"], c["state_size"]
+    seq, cols, a = T * di, T * N, di * N  # dt-like, B-like, A-like
+    fwd = 3 * seq + a + 2 * cols
+    bwd = (3 * seq + a + 2 * cols) + (2 * seq + 2 * cols + m * a)
+    calls = c["num_hidden_layers"] * K
+    return {"ssm_scan": 4.0 * calls * (fwd + bwd)}

@@ -20,7 +20,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from . import compare, flops, peaks, reference, registry
+from . import compare, flops, peaks, reference, registry, scopes
 from . import trace as trace_mod
 
 ROOT = registry.ROOT
@@ -204,6 +204,7 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool, devices,
         # None off a TPU (the CPU tests); `require_devices` refuses an
         # unknown chip before a run starts
         "peak": peaks.PEAKS.get(dev.device_kind), "trace": summary,
+        "config": c, "traffic": t,
     }
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}
@@ -231,6 +232,9 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool, devices,
 
 
 def _trace(prog: Program, n: int) -> Dict:
+    """`trace.reduce`'s summary of n profiled rounds, with under
+    `scopes` the split of `scopes.reduce` by the compiled round's
+    scope names and by kernel."""
     d = tempfile.mkdtemp(prefix="chipbench-trace-")
     try:
         jax.profiler.start_trace(d)
@@ -238,7 +242,9 @@ def _trace(prog: Program, n: int) -> Dict:
             prog.traced(n)
         finally:
             jax.profiler.stop_trace()
-        return trace_mod.reduce(trace_mod.load(trace_mod.find_xplane(d)),
-                                host_files=HOST_FILES)
+        events = trace_mod.load(trace_mod.find_xplane(d))
     finally:
         shutil.rmtree(d, ignore_errors=True)
+    summary = trace_mod.reduce(events, host_files=HOST_FILES)
+    summary["scopes"] = scopes.reduce(events, scopes.op_scopes(prog.rnd.as_text()))
+    return summary

@@ -5,15 +5,27 @@
 - correctness limits of cell `<w>`: `chipbench/limits/<w>.json`;
 - metric `<m>`: module `chipbench/metrics/<m>.py`, whose `read(ctx)`
   returns the number or None where the run has nothing to read; a cell
-  reports the metrics whose readers find something in it;
+  reports the metrics whose readers find something in it, of those
+  whose entry lists the cell under `workloads` or has no such list.
+  `ctx` (`harness.run_cell`) holds the run's times, memory and device,
+  the cell's `config` and `traffic`, and with a trace `trace`:
+  `trace.reduce`'s summary, whose `scopes` is `scopes.reduce`'s split
+  (`scope_s`, device seconds per round under each scope name the
+  program gives, and `kernel_s`, by kernel name).  A reader of a new
+  scope or kernel needs only the name; one of a kernel's roofline takes
+  its bytes or FLOPs from the architecture module and `peaks.share`.
+  An optional `EXAMPLE = (ctx, value)` in the module is a filled run
+  and what it reads there, for the tests;
 - architecture `<a>`, the `arch` key of a configuration file: module
   `chipbench/archs/<a>.py`, everything of the benchmark that knows the
   model.  It gives `program_args(c)`, the `launch/train.py` arguments
   that cut the program to the file; `program_fields(c)`, the program's
   `ModelConfig` fields as the file states them; `init_params(key, c,
   dtype)` and `agent_loss(params, delta, batch, c)`, the plain float32
-  reference model; and `forward_flops(c, batch, seq, *, causal_half)`,
-  its forward FLOPs split into `matmul` and `elementwise`.
+  reference model; `forward_flops(c, batch, seq, *, causal_half)`,
+  its forward FLOPs split into `matmul` and `elementwise`; and, where
+  the model runs kernels of its own, `kernel_bytes(c, traffic)` or
+  `kernel_flops(c, traffic)`, per round by kernel name.
   `chipbench/archs/common.py` holds the parts they share.
 
 A later cell, configuration, architecture or metric is a new file and a
@@ -42,8 +54,8 @@ def _json(kind: str, name: str) -> Dict:
     return json.loads(path.read_text())
 
 
-def config(entry: Dict, root: pathlib.Path = ROOT) -> Dict:
-    return json.loads((root / entry["file"]).read_text())
+def config(entry: Dict) -> Dict:
+    return json.loads((ROOT / entry["file"]).read_text())
 
 
 def traffic(name: str) -> Dict:
@@ -85,7 +97,8 @@ def cell(bench: Dict, workload: str) -> Dict:
     cfg_entry = next(c for c in bench["configs"] if c["name"] == w["config"])
 
     def readers(kind: str) -> List:
-        return [(m, metric(m["name"])) for m in bench[kind]]
+        return [(m, metric(m["name"])) for m in bench[kind]
+                if workload in m.get("workloads", [workload])]
 
     return {
         "workload": w,

@@ -2,29 +2,34 @@
 
 `core/engine.py make_phases` runs each of the round's four phases under
 `jax.named_scope(<phase>)` and each loss-gradient call under
-`jax.named_scope("loss_grad")`.  The names reach the compiled module as
+`jax.named_scope("loss_grad")`; the model and its kernels may name
+scopes of their own (`kernels/ssm_scan.py`: `ssm_scan`).  The names
+reach the compiled module as
 `metadata={op_name="jit(round)/<phase>/loss_grad/..."}`.
 
 - `op_scopes(hlo_text)` maps each instruction of the compiled module
-  (`Compiled.as_text()`) to `(phase, loss_grad)`: the first phase that
-  its `op_name` path names (a merged op lists several paths, `;` apart)
-  and whether that path passes through `loss_grad`, read for a fusion
-  from the op it returns; where that names no phase, where the op's own
-  metadata, its users or operands, or its loop say (see `op_scopes`);
-  else `(None, False)`.
+  (`Compiled.as_text()`) to `(phase, names)`: the first phase that its
+  `op_name` path names (a merged op lists several paths, `;` apart) and
+  every scope name on that path, read for a fusion from the op it
+  returns; where that names no phase, where the op's own metadata, its
+  users or operands, or its loop say (see `op_scopes`); else
+  `(None, frozenset())`.
 - `reduce(events, op_scopes)` takes the events of `trace.load` and, over
   the window of `trace.reduce`, gives each instant of a device's busy
   time to the innermost operation running then (the one that started
   last; on a tie the shorter), and so to that operation's phase, or to
-  "unscoped" when the operation is not in the map or names no phase.
-  The four phases and "unscoped" sum to the device's busy union; the
-  seconds are the mean over devices, per `round` host span.
+  "unscoped" when the operation is not in the map or names no phase,
+  and to every scope name on its path (`scope_s`).  The four phases and
+  "unscoped" sum to the device's busy union.  Apart from that split,
+  `kernel_s` sums each operation's own time by its instruction's base
+  name.  The seconds are the mean over devices, per `round` host span.
 """
 from __future__ import annotations
 
 import heapq
 import re
-from typing import Dict, List, Optional, Tuple
+from collections import defaultdict
+from typing import Dict, FrozenSet, List, Optional, Tuple
 
 from .trace import WINDOW_SPANS
 
@@ -33,7 +38,8 @@ LOSS_GRAD = "loss_grad"
 #: the keys of `reduce`'s seconds per round, each a per-layer metric
 METRICS = tuple(f"{p}_s" for p in PHASES) + ("loss_grad_s", "unscoped_s")
 
-Scope = Tuple[Optional[str], bool]
+Scope = Tuple[Optional[str], FrozenSet[str]]
+NO_SCOPE: Scope = (None, frozenset())
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
@@ -41,17 +47,23 @@ _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _REF = re.compile(r"%([\w.\-]+)")
 _OP = re.compile(r"^%?([^\s=]+)")
 _TOKEN = re.compile(r"[\w.\-]+")
+_JIT = re.compile(r"^jit\(.*\)$")
+_SUFFIX = re.compile(r"\.\d+$")
 
 
 def path_scope(op_name: str) -> Optional[Scope]:
-    """(phase, loss_grad) of an `op_name`: the first of its `;`-separated
-    paths that names a phase, or None.  The last component of a path is
-    the primitive, and names nothing."""
+    """(phase, names) of an `op_name`: the first of its `;`-separated
+    paths that names a phase, or None.  `names` are the tokens of the
+    path's components but the last, the primitive, which names nothing,
+    and the first where it is the program's `jit(...)` wrapper."""
     for path in op_name.split(";"):
-        scopes = [t for c in path.split("/")[:-1] for t in _TOKEN.findall(c)]
-        phase = next((t for t in scopes if t in PHASES), None)
+        comps = path.split("/")[:-1]
+        if comps and _JIT.match(comps[0]):
+            comps = comps[1:]
+        names = [t for c in comps for t in _TOKEN.findall(c)]
+        phase = next((t for t in names if t in PHASES), None)
         if phase is not None:
-            return phase, LOSS_GRAD in scopes
+            return phase, frozenset(names)
     return None
 
 
@@ -70,8 +82,8 @@ def instructions(hlo_text: str):
 
 
 def op_scopes(hlo_text: str) -> Dict[str, Scope]:
-    """Each instruction's (phase, loss_grad), from the first of these
-    that names a phase:
+    """Each instruction's (phase, names), from the first of these that
+    names a phase:
 
     1. for a fusion, the `op_name` of its fused computation's root, the
        op whose result the kernel returns (XLA gives a fusion the
@@ -123,7 +135,7 @@ def op_scopes(hlo_text: str) -> Dict[str, Scope]:
             down[n] = base[n] or next((down[u] for u in users[n] if down[u]), None)
     out: Dict[str, Scope] = {}
     for comp in reversed(list(comps)):  # callers print after their callees
-        via = out.get(caller.get(comp, ""), (None, False))
+        via = out.get(caller.get(comp, ""), NO_SCOPE)
         for n in comps[comp]:
             out[n] = down[n] or up[n] or via
     return out
@@ -134,6 +146,12 @@ def op_name(event_name: str) -> str:
     fusion(...)` -> `fusion.33`."""
     m = _OP.match(event_name)
     return m.group(1) if m else event_name
+
+
+def base_name(event_name: str) -> str:
+    """The instruction name without its trailing `.N`: `ssm_scan_bwd.5`
+    and `ssm_scan_bwd.6` -> `ssm_scan_bwd`."""
+    return _SUFFIX.sub("", op_name(event_name))
 
 
 def _innermost(ops, lo: float, hi: float):
@@ -159,8 +177,13 @@ def _innermost(ops, lo: float, hi: float):
 def reduce(events: Dict, scopes: Dict[str, Scope]) -> Dict:
     """Per round, mean over devices: `<phase>_s`, `unscoped_s`,
     `loss_grad_s` and `busy_s` in seconds, `unscoped_share` of busy;
-    with `rounds` and `named_ops`, the instructions the map gives a
-    phase (0 for a program without the names)."""
+    `scope_s`, the busy seconds under each scope name seen, inclusive
+    (an instant counts toward every name on its innermost op's path, so
+    `scope_s[<phase>]` is `<phase>_s`); `kernel_s`, each op's own
+    seconds inside the window summed by `base_name`, overlaps and all,
+    as `trace.reduce` ranks `device_ops`; with `rounds` and `named_ops`,
+    the instructions the map gives a phase (0 for a program without the
+    names)."""
     host = events["host"]
     marks = [(s, s + d) for n, s, d in host if n in WINDOW_SPANS]
     rounds = sum(1 for n, _, _ in host if n == "round")
@@ -169,15 +192,25 @@ def reduce(events: Dict, scopes: Dict[str, Scope]) -> Dict:
     lo, hi = min(a for a, _ in marks), max(b for _, b in marks)
     n_dev = len(events["devices"])
     sums = dict.fromkeys(METRICS + ("busy_s",), 0.0)
+    scope_s: Dict[str, float] = defaultdict(float)
+    kernel_s: Dict[str, float] = defaultdict(float)
     for ops in events["devices"].values():
         for sec, name in _innermost(ops, lo, hi):
-            phase, grad = scopes.get(op_name(name), (None, False))
+            phase, names = scopes.get(op_name(name), NO_SCOPE)
             sums[f"{phase}_s" if phase else "unscoped_s"] += sec
-            sums["loss_grad_s"] += sec if grad else 0.0
             sums["busy_s"] += sec
-    out = {k: v / n_dev / rounds for k, v in sums.items()}
+            for n in names:
+                scope_s[n] += sec
+        for name, s, d in ops:
+            a, b = max(s, lo), min(s + d, hi)
+            if b > a:
+                kernel_s[base_name(name)] += (b - a) * 1e-9
+    sums["loss_grad_s"] = scope_s.get(LOSS_GRAD, 0.0)
+    per = lambda d: {k: v / n_dev / rounds for k, v in d.items()}
+    out = per(sums)
     out["unscoped_share"] = out["unscoped_s"] / out["busy_s"] if out["busy_s"] else 0.0
+    out["scope_s"] = per(scope_s)
+    out["kernel_s"] = per(kernel_s)
     out["rounds"] = rounds
     out["named_ops"] = sum(1 for p, _ in scopes.values() if p is not None)
     return out
-

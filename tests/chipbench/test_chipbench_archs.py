@@ -2,7 +2,6 @@
 `chipbench/archs/<arch>.py`, found by the file's `arch` key: the
 program check, the reference model and the FLOP count all resolve
 through it, so a new architecture is new files alone."""
-import hashlib
 import json
 import shutil
 
@@ -141,18 +140,19 @@ def test_shared_files_name_no_layer_kind(shared):
     kinds |= {k for cfg, _ in tiny.KINDS.values() for k in cfg["layer_pattern"]}
     for kind in kinds:
         assert f'"{kind}"' not in text, kind
-
-
-def _digest(root):
-    return {p.relative_to(root): hashlib.sha256(p.read_bytes()).hexdigest()
-            for p in sorted(root.rglob("*")) if p.is_file() and "__pycache__" not in p.parts}
+    kernels = {k for cfg, _ in tiny.KINDS.values()
+               for k in getattr(registry.arch(cfg["arch"]), "kernel_bytes",
+                                lambda c, t: {})(cfg, tiny.TRAFFIC)}
+    assert kernels == {"ssm_scan"}
+    for kernel in kernels:
+        assert kernel not in text, kernel
 
 
 def test_a_new_architecture_is_new_files_alone(tmp_path, monkeypatch):
     """A third architecture, here granite's module under another name,
     with a configuration file that names it: the program check, the
     reference and the FLOP count all resolve through the new module."""
-    before = _digest(registry.HERE)
+    before = tiny.digest(registry.HERE)
     archs = tmp_path / "archs"
     archs.mkdir()
     shutil.copy(registry.ARCHS / "granite-8b.py", archs / "granite-copy.py")
@@ -178,7 +178,7 @@ def test_a_new_architecture_is_new_files_alone(tmp_path, monkeypatch):
         np.testing.assert_array_equal(a, b)
     assert loss == float(reference.agent_loss(x, y, batch, d))
     assert fl == flops.round_flops(d, tiny.TRAFFIC)
-    assert _digest(registry.HERE) == before
+    assert tiny.digest(registry.HERE) == before
 
 
 @pytest.mark.parametrize("entry", ["program_config", "init_params", "agent_loss",

@@ -1,6 +1,7 @@
-"""The split of device time by the engine's scope names
+"""The split of device time by the program's scope names
 (`chipbench.scopes`): instructions of the compiled round mapped to their
-phase, and busy time given to the innermost operation's phase."""
+phase and scope names, busy time given to the innermost operation's
+phase and names, and each kernel's own time by name."""
 import pathlib
 import re
 
@@ -8,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from chipbench import harness, scopes, trace
+from chipbench import harness, peaks, registry, scopes, trace
 
 import tiny
 
@@ -46,23 +47,27 @@ def test_every_kernel_of_the_round_maps_to_one_phase(compiled_round):
 
 def test_loss_grad_runs_in_the_exchange_and_the_local_steps(compiled_round):
     m = scopes.op_scopes(compiled_round)
-    assert {p for p, grad in m.values() if grad} \
+    assert {p for p, names in m.values() if scopes.LOSS_GRAD in names} \
         == {"exchange_corrections", "local_steps"}
     assert {p for p, _ in m.values()} >= set(scopes.PHASES)
 
 
 @pytest.mark.parametrize("op_name,scope", [
     ("jit(round)/local_steps/loss_grad/vmap(jvp())/while/body/dot_general",
-     ("local_steps", True)),
+     ("local_steps", {"local_steps", "loss_grad", "vmap", "jvp", "while", "body"})),
     ("jit(round)/exchange_corrections/loss_grad/vmap(transpose(jvp()))/mul",
-     ("exchange_corrections", True)),
-    ("jit(round)/cos;jit(round)/aggregate/div", ("aggregate", False)),
-    ("jit(round)/broadcast/broadcast_in_dim", ("broadcast", False)),
+     ("exchange_corrections",
+      {"exchange_corrections", "loss_grad", "vmap", "transpose", "jvp"})),
+    ("jit(round)/cos;jit(round)/aggregate/div", ("aggregate", {"aggregate"})),
+    ("jit(round)/broadcast/broadcast_in_dim", ("broadcast", {"broadcast"})),
+    ("jit(round)/local_steps/mla/ssm_scan/dot", ("local_steps",
+                                                 {"local_steps", "mla", "ssm_scan"})),
     ("jit(round)/cos", None),
     ("jit(round)/aggregate", None),  # the last component is the primitive
 ])
 def test_path_scope_reads_the_first_path_naming_a_phase(op_name, scope):
-    assert scopes.path_scope(op_name) == scope
+    got = scopes.path_scope(op_name)
+    assert got == (scope and (scope[0], frozenset(scope[1])))
 
 
 @pytest.mark.parametrize("event,name", [
@@ -113,7 +118,8 @@ ENTRY %main.9 (Arg_0.1: f32[4]) -> (f32[4], s32[4]) {
 
 
 def test_op_scopes_fill_in_ops_without_a_phase():
-    m = scopes.op_scopes(HLO)
+    named = scopes.op_scopes(HLO)
+    m = {k: (p, scopes.LOSS_GRAD in names) for k, (p, names) in named.items()}
     lg_local, lg_exch = ("local_steps", True), ("exchange_corrections", True)
     assert m["fusion.12"] == lg_local  # its fused root, before its own
     assert m["copy-start.10"] == m["copy-done.11"] == lg_local  # from users
@@ -123,6 +129,12 @@ def test_op_scopes_fill_in_ops_without_a_phase():
     assert m["cosine.17"] == ("aggregate", False)  # a hoisted invariant
     assert m["gte.16"] == ("aggregate", False)
     assert m["iota.19"] == (None, False)  # nothing around it names one
+    # the names come with the phase, from the path that gave it
+    assert named["fusion.12"][1] == {"local_steps", "loss_grad", "vmap", "jvp"}
+    assert named["copy.4"][1] == named["while.15"][1] \
+        == {"exchange_corrections", "loss_grad"}
+    assert named["multiply.18"][1] == {"aggregate"}
+    assert named["iota.19"] == scopes.NO_SCOPE
 
 
 def _synthetic():
@@ -141,13 +153,15 @@ def _synthetic():
             ("copy.7", 17 * MS, 1 * MS),       # not in the map
             ("fusion.8", 25 * MS, 1 * MS)]     # outside the window
     dev1 = [("fusion.2", 0, 20 * MS)]
-    m = {"while.1": ("exchange_corrections", True),
-         "fusion.2": ("exchange_corrections", True),
-         "fusion.3": ("aggregate", False),
-         "fusion.4": ("local_steps", True),
-         "fusion.5": ("broadcast", False),
-         "fusion.6": ("aggregate", False),
-         "fusion.8": ("broadcast", False)}
+    grad = lambda p, *more: (p, frozenset({p, "loss_grad", *more}))
+    plain = lambda p: (p, frozenset({p}))
+    m = {"while.1": grad("exchange_corrections"),
+         "fusion.2": grad("exchange_corrections", "ssm_scan"),
+         "fusion.3": plain("aggregate"),
+         "fusion.4": grad("local_steps", "ssm_scan"),
+         "fusion.5": plain("broadcast"),
+         "fusion.6": plain("aggregate"),
+         "fusion.8": plain("broadcast")}
     return {"devices": {"/device:TPU:0": dev0, "/device:TPU:1": dev1},
             "host": host}, m
 
@@ -172,6 +186,47 @@ def test_reduce_gives_each_instant_to_the_innermost_op():
     parts = sum(r[k] for k in scopes.METRICS if k != "loss_grad_s")
     assert parts == pytest.approx(r["busy_s"], rel=1e-12)
     assert r["busy_s"] * r["rounds"] == pytest.approx(trace.reduce(ev)["busy_s"])
+    # every name on the innermost op's path: fusion.2 2 + 20, fusion.4 4
+    assert r["scope_s"]["ssm_scan"] == pytest.approx(26 * ms)
+    for p in scopes.PHASES:
+        assert r["scope_s"][p] == r[f"{p}_s"]
+    assert r["scope_s"]["loss_grad"] == r["loss_grad_s"]
+    # own time inside the window by base name: fusion.2 2 + 20, .3 2,
+    # .4 4, .5 4, .6 2 (fusion.8 lies outside)
+    assert r["kernel_s"] == pytest.approx(
+        {"while": 8 * ms, "fusion": 34 * ms, "copy": 1 * ms})
+
+
+def test_scope_seconds_of_each_phase_are_its_phase_seconds(compiled_round):
+    """On the tiny compiled round, every instruction run once in a row:
+    the inclusive seconds of each phase name and of `loss_grad` are the
+    phase split's, to the last digit."""
+    m = scopes.op_scopes(compiled_round)
+    r = scopes.reduce(tiny.one_round(sorted(m)), m)
+    assert r["named_ops"] == sum(1 for p, _ in m.values() if p)
+    for p in scopes.PHASES:
+        assert r["scope_s"][p] == r[f"{p}_s"] > 0
+    assert r["scope_s"]["loss_grad"] == r["loss_grad_s"] > 0
+    assert set(r["scope_s"]) >= {"while", "body"}  # names JAX gives scopes too
+    parts = sum(r[k] for k in scopes.METRICS if k != "loss_grad_s")
+    assert parts == pytest.approx(r["busy_s"], rel=1e-12)
+
+
+def test_kernel_seconds_are_each_ops_own_time_by_base_name():
+    """An async copy that starts inside a kernel takes nothing from the
+    kernel's own time, which the innermost split would give it; `.N`
+    suffixes fold into one name; time outside the window is cut."""
+    host = [("round", 0, 10 * MS), ("round", 10 * MS, 10 * MS)]
+    dev = [("%ssm_scan_bwd.5 = f32[2,1,512,8192]{3,2,1,0} custom-call(%a)", 1 * MS, 4 * MS),
+           ("copy-start.3", 2 * MS, 1 * MS),       # inside the kernel
+           ("ssm_scan_bwd.6", 12 * MS, 3 * MS),
+           ("ssm_scan_fwd.1", 19 * MS, 2 * MS)]   # half outside
+    r = scopes.reduce({"devices": {"/device:TPU:0": dev}, "host": host}, {})
+    ms = 1e-3 / 2
+    assert r["kernel_s"] == pytest.approx(
+        {"ssm_scan_bwd": 7 * ms, "copy-start": 1 * ms, "ssm_scan_fwd": 1 * ms})
+    assert scopes.base_name("%fusion.33 = f32[8] fusion(%p)") == "fusion"
+    assert scopes.base_name("reduce-window") == "reduce-window"
 
 
 def test_reduce_needs_a_round_span_and_device_ops():
@@ -193,5 +248,39 @@ def test_a_recorded_v5e_trace_with_an_empty_map_is_all_unscoped():
     assert r["rounds"] == 3
     assert r["busy_s"] * 3 == pytest.approx(before["busy_s"])
     assert all(r[f"{p}_s"] == 0.0 for p in scopes.PHASES)
+    assert r["scope_s"] == {}
+    every_op = trace.reduce(ev, host_files=(), top=10**6)["device_ops"]
+    assert sum(r["kernel_s"].values()) * 3 == pytest.approx(sum(v for _, v in every_op))
     assert trace.reduce(ev, host_files=()) == before
     assert before["busy_s"] == pytest.approx(3.139e-06)
+
+
+V5E = peaks.PEAKS["TPU v5 lite"]
+
+
+@pytest.mark.parametrize("moved,flops", [(819e6, None), (819e6, 1e9), (None, 197e9)])
+def test_roofline_share_is_one_at_exactly_the_peak(moved, flops):
+    """Work moved or computed at exactly the peak it is bound by takes
+    the whole time; a second as long halves the share."""
+    t = max(moved / 819e9 if moved else 0, flops / 197e12 if flops else 0)
+    assert peaks.share(t, V5E, bytes=moved, flops=flops) == pytest.approx(1.0, rel=1e-12)
+    assert peaks.share(2 * t, V5E, bytes=moved, flops=flops) == pytest.approx(0.5)
+    assert peaks.share(0.0, V5E, bytes=moved, flops=flops) is None
+    assert peaks.share(None, V5E, bytes=moved, flops=flops) is None
+
+
+def test_roofline_share_needs_a_term():
+    with pytest.raises(ValueError):
+        peaks.share(1.0, V5E)
+
+
+def test_falcon_mamba_kernel_bytes_are_the_hand_count_at_tiny_shapes():
+    """Tiny mamba: m = 4 agents x 1 x 32 tokens (128 rows x steps),
+    d_inner 512, state 16, one layer, K = 2; float32."""
+    seq, cols, a = 128 * 512 * 4, 128 * 16 * 4, 512 * 16 * 4
+    fwd = 3 * seq + a + 2 * cols  # dt, x in, y out; A; B, C
+    bwd = 3 * seq + a + 2 * cols + 2 * seq + 2 * cols + 4 * a  # + ddt, dx, dB, dC, dA x 4
+    assert (fwd, bwd) == (835584, 1507328)
+    cfg, _ = tiny.KINDS["mamba"]
+    got = registry.arch(cfg["arch"]).kernel_bytes(cfg, tiny.TRAFFIC)
+    assert got == {"ssm_scan": 2 * (fwd + bwd)}

@@ -1,6 +1,9 @@
 """Small cells for the benchmark's CPU tests: the registry's reduced
-widths, one layer, a few agents with short sequences."""
+widths, one layer, a few agents with short sequences; a stand-in for a
+profiler's trace of a round, which no CPU run records; and a digest of
+the files under a directory, to show that none changed."""
 import copy
+import hashlib
 
 from chipbench import registry
 
@@ -40,3 +43,17 @@ def devices():
     import jax
 
     return jax.devices()[:1]
+
+
+def one_round(names, step_ns=10_000, dur_ns=15_000):
+    """`trace.load`'s events of one round on one device in which the
+    named ops run one after another, each overlapping the next."""
+    ops = [(n, i * step_ns, dur_ns) for i, n in enumerate(names)]
+    end = len(names) * step_ns + dur_ns
+    return {"devices": {"/device:TPU:0": ops}, "host": [("round", 0, end)]}
+
+
+def digest(root):
+    """{path under root: SHA-256} of every file but compiled Python."""
+    return {p.relative_to(root): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in sorted(root.rglob("*")) if p.is_file() and "__pycache__" not in p.parts}
